@@ -332,7 +332,6 @@ def _solve_sharded(f, params, z0, grid, nb, solver, controller, gradient,
     """Data-parallel fleet: shard_map the inner batched driver over one
     mesh axis, one shard of the batch per device group (the serving path —
     reuses the ambient production/host mesh, see repro.launch.mesh)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import ambient_mesh
@@ -366,8 +365,8 @@ def _solve_sharded(f, params, z0, grid, nb, solver, controller, gradient,
                                controller, gradient, trajectory)
 
     spec = P(batching.axis)
-    ys, per = shard_map(shard_body, mesh=mesh, in_specs=(P(), spec),
-                        out_specs=(spec, spec), check_rep=False)(params, z0)
+    ys, per = jax.shard_map(shard_body, mesh=mesh, in_specs=(P(), spec),
+                            out_specs=(spec, spec), check_vma=False)(params, z0)
     return ys, per
 
 

@@ -300,6 +300,8 @@ def hint(x, *dims: Optional[str]):
     mesh = mesh_lib.thread_resources.env.physical_mesh
     if mesh.empty or mesh.size == 1:
         return x
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return x   # inside a shard_map body (Sharded ODE): device-local
     spec = []
     for dim, want in zip(x.shape, dims):
         if want == "batch":

@@ -5,8 +5,14 @@ Tiling: the state is flattened to [rows, 128] (lane-aligned) and tiled in
 lanes match the VPU, block_rows sized so in+out blocks fit comfortably in
 VMEM (default 1024 rows -> 5 x 512KB f32 blocks per program).
 
-The step size ``h`` is prefetched as a scalar (SMEM) so one compiled kernel
-serves every step of an adaptive integration.
+The step size ``h`` rides as a (1, 1) array in SMEM (a runtime scalar, not
+a compile-time constant), so one compiled kernel serves every step of an
+adaptive integration. Two dimensions, because Mosaic tiles the last two
+dims of every block: under ``vmap`` (per-sample step sizes) h becomes
+(B, 1, 1) and each program's (1, 1) block still spans them whole.
+
+Interpret mode follows the lowering platform (``repro.kernels.dispatch``):
+compiled Mosaic on TPU, the Pallas interpreter on CPU.
 
 Kernel inventory (the jnp oracle for each lives in ref.py):
 
@@ -30,6 +36,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from repro.distributed.sharding import ambient_mesh
+from repro.kernels.dispatch import pallas_call
 
 LANES = 128
 BLOCK_ROWS = 1024
@@ -41,7 +52,7 @@ def _acc(x):
 
 
 def _midpoint_kernel(h_ref, z_ref, v_ref, k1_ref, *, sign: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     z = _acc(z_ref[...])
     v = _acc(v_ref[...])
     k1_ref[...] = (z + sign * v * (h * 0.5)).astype(k1_ref.dtype)
@@ -49,7 +60,7 @@ def _midpoint_kernel(h_ref, z_ref, v_ref, k1_ref, *, sign: float):
 
 def _update_kernel(h_ref, k1_ref, v_ref, u1_ref, z_out_ref, v_out_ref, *,
                    eta: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     k1 = _acc(k1_ref[...])
     v = _acc(v_ref[...])
     u1 = _acc(u1_ref[...])
@@ -60,7 +71,7 @@ def _update_kernel(h_ref, k1_ref, v_ref, u1_ref, z_out_ref, v_out_ref, *,
 
 def _inverse_update_kernel(h_ref, k1_ref, vo_ref, u1_ref, z_in_ref, v_in_ref,
                            *, eta: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     k1 = _acc(k1_ref[...])
     vo = _acc(vo_ref[...])
     u1 = _acc(u1_ref[...])
@@ -75,7 +86,7 @@ def _inverse_update_kernel(h_ref, k1_ref, vo_ref, u1_ref, z_in_ref, v_in_ref,
 def _inverse_kernel(h_ref, zo_ref, vo_ref, u1_ref, z_in_ref, v_in_ref, *,
                     eta: float):
     """Full psi^-1: midpoint recovery + inverse tail in one pass."""
-    h = h_ref[0]
+    h = h_ref[0, 0]
     zo = _acc(zo_ref[...])
     vo = _acc(vo_ref[...])
     u1 = _acc(u1_ref[...])
@@ -89,14 +100,14 @@ def _inverse_kernel(h_ref, zo_ref, vo_ref, u1_ref, z_in_ref, v_in_ref, *,
 
 
 def _midpoint_vjp_kernel(h_ref, g_ref, vbar_ref, *, sign: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     g = _acc(g_ref[...])
     vbar_ref[...] = (sign * g * (h * 0.5)).astype(vbar_ref.dtype)
 
 
 def _update_vjp_kernel(h_ref, gz_ref, gv_ref, vbar_ref, ubar_ref, *,
                        eta: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     gz = _acc(gz_ref[...])
     gv = _acc(gv_ref[...])
     cot_vout = gv + gz * (h * 0.5)
@@ -106,7 +117,7 @@ def _update_vjp_kernel(h_ref, gz_ref, gv_ref, vbar_ref, ubar_ref, *,
 
 def _bwd_pre_kernel(h_ref, z_ref, v_ref, az_ref, av_ref, k1_ref, cu_ref, *,
                     eta: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     z = _acc(z_ref[...])
     v = _acc(v_ref[...])
     az = _acc(az_ref[...])
@@ -117,7 +128,7 @@ def _bwd_pre_kernel(h_ref, z_ref, v_ref, az_ref, av_ref, k1_ref, cu_ref, *,
 
 def _bwd_post_kernel(h_ref, k1_ref, vo_ref, u1_ref, az_ref, av_ref, dk1_ref,
                      zp_ref, vp_ref, dz_ref, dv_ref, *, eta: float):
-    h = h_ref[0]
+    h = h_ref[0, 0]
     k1 = _acc(k1_ref[...])
     vo = _acc(vo_ref[...])
     u1 = _acc(u1_ref[...])
@@ -137,9 +148,8 @@ def _bwd_post_kernel(h_ref, k1_ref, vo_ref, u1_ref, az_ref, av_ref, dk1_ref,
                    + (1.0 - 2.0 * eta) * cot_vout).astype(dv_ref.dtype)
 
 
-def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS, interpret=True):
-    """args: (h_scalar, *arrays) with arrays pre-shaped [rows, LANES]."""
-    h, *arrays = args
+def _local_call(kernel, h, arrays, n_out, block_rows):
+    """One launch over [rows, LANES] arrays on one device."""
     rows = arrays[0].shape[0]
     bs = min(block_rows, rows)
     # Pad rows to a block multiple: an unguarded `rows // bs` grid covers
@@ -155,18 +165,18 @@ def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS, interpret=True):
     out_shape = tuple(
         jax.ShapeDtypeStruct((rows_p, LANES), a.dtype)
         for a in arrays[:n_out])
-    fn = pl.pallas_call(
+    fn = pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))] + [spec] * len(arrays),
+        in_specs=([pl.BlockSpec(memory_space=pltpu.SMEM)]
+                  + [spec] * len(arrays)),
         out_specs=(spec,) * n_out if n_out > 1 else spec,
         out_shape=out_shape if n_out > 1 else out_shape[0],
-        interpret=interpret,
     )
-    # h rides at >= f32 whatever the block storage dtype (a bf16 h would
-    # quantize small adaptive steps); f64 blocks get an f64 h under x64.
-    h_dtype = jnp.promote_types(arrays[0].dtype, jnp.float32)
-    out = fn(jnp.asarray(h, h_dtype).reshape(1), *arrays)
+    return _unpad(fn(h, *arrays), rows, pad, n_out)
+
+
+def _unpad(out, rows, pad, n_out):
     if not pad:
         return out
     if n_out > 1:
@@ -174,49 +184,82 @@ def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS, interpret=True):
     return out[:rows]
 
 
-def midpoint_call(z, v, h, *, sign=1.0, interpret=True, block_rows=BLOCK_ROWS):
+def _row_mesh():
+    """The mesh to split rows over, or None: the ambient multi-device mesh
+    of a ``with mesh:`` context, unless the caller is already inside a
+    shard_map body (manual axes), where the arrays are device-local."""
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mesh
+
+
+def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS):
+    """args: (h_scalar, *arrays) with arrays pre-shaped [rows, LANES].
+
+    Under a multi-device mesh the launch is shard-local: GSPMD cannot
+    partition a Mosaic kernel, and every op here is elementwise, so each
+    device runs the kernel on its own slice of rows (a shard_map over all
+    mesh axes, rows zero-padded to a multiple of the device count).
+    """
+    h, *arrays = args
+    # h rides at >= f32 whatever the block storage dtype (a bf16 h would
+    # quantize small adaptive steps); f64 blocks get an f64 h under x64.
+    h = jnp.asarray(h, jnp.promote_types(arrays[0].dtype, jnp.float32))
+    h = h.reshape(1, 1)
+    mesh = _row_mesh()
+    if mesh is None:
+        return _local_call(kernel, h, arrays, n_out, block_rows)
+    rows = arrays[0].shape[0]
+    pad = (-rows) % mesh.size
+    if pad:
+        arrays = [jnp.pad(a, ((0, pad), (0, 0))) for a in arrays]
+    rows_spec = P(tuple(mesh.axis_names))
+    out = jax.shard_map(
+        lambda h_, *a: _local_call(kernel, h_, list(a), n_out, block_rows),
+        mesh=mesh, in_specs=(P(),) + (rows_spec,) * len(arrays),
+        out_specs=(rows_spec,) * n_out if n_out > 1 else rows_spec,
+        check_vma=False)(h, *arrays)
+    return _unpad(out, rows, pad, n_out)
+
+
+def midpoint_call(z, v, h, *, sign=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_midpoint_kernel, sign=sign),
-                       (h, z, v), 1, block_rows, interpret)
+                       (h, z, v), 1, block_rows)
 
 
-def update_call(k1, v, u1, h, *, eta=1.0, interpret=True,
-                block_rows=BLOCK_ROWS):
+def update_call(k1, v, u1, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_update_kernel, eta=eta),
-                       (h, k1, v, u1), 2, block_rows, interpret)
+                       (h, k1, v, u1), 2, block_rows)
 
 
-def inverse_update_call(k1, v_out, u1, h, *, eta=1.0, interpret=True,
-                        block_rows=BLOCK_ROWS):
+def inverse_update_call(k1, v_out, u1, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_inverse_update_kernel, eta=eta),
-                       (h, k1, v_out, u1), 2, block_rows, interpret)
+                       (h, k1, v_out, u1), 2, block_rows)
 
 
-def inverse_call(z_out, v_out, u1, h, *, eta=1.0, interpret=True,
-                 block_rows=BLOCK_ROWS):
+def inverse_call(z_out, v_out, u1, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_inverse_kernel, eta=eta),
-                       (h, z_out, v_out, u1), 2, block_rows, interpret)
+                       (h, z_out, v_out, u1), 2, block_rows)
 
 
-def midpoint_vjp_call(g, h, *, sign=1.0, interpret=True,
-                      block_rows=BLOCK_ROWS):
+def midpoint_vjp_call(g, h, *, sign=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_midpoint_vjp_kernel, sign=sign),
-                       (h, g), 1, block_rows, interpret)
+                       (h, g), 1, block_rows)
 
 
-def update_vjp_call(g_z, g_v, h, *, eta=1.0, interpret=True,
-                    block_rows=BLOCK_ROWS):
+def update_vjp_call(g_z, g_v, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_update_vjp_kernel, eta=eta),
-                       (h, g_z, g_v), 2, block_rows, interpret)
+                       (h, g_z, g_v), 2, block_rows)
 
 
-def bwd_pre_call(z, v, a_z, a_v, h, *, eta=1.0, interpret=True,
-                 block_rows=BLOCK_ROWS):
+def bwd_pre_call(z, v, a_z, a_v, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_bwd_pre_kernel, eta=eta),
-                       (h, z, v, a_z, a_v), 2, block_rows, interpret)
+                       (h, z, v, a_z, a_v), 2, block_rows)
 
 
 def bwd_post_call(k1, v_out, u1, a_z, a_v, dk1, h, *, eta=1.0,
-                  interpret=True, block_rows=BLOCK_ROWS):
+                  block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_bwd_post_kernel, eta=eta),
-                       (h, k1, v_out, u1, a_z, a_v, dk1), 4, block_rows,
-                       interpret)
+                       (h, k1, v_out, u1, a_z, a_v, dk1), 4, block_rows)

@@ -7,9 +7,10 @@ x64 stay f64), processed by one kernel launch, and split back with every
 leaf's original dtype restored — so the whole model state is one fused
 elementwise pass regardless of parameter structure.
 
-``use_pallas=False`` (the CPU-container default) routes to the jnp oracle —
-identical math, XLA-fused; the Pallas path (interpret=True on CPU, compiled
-on TPU) is validated against it in tests.
+``use_pallas=False`` routes to the jnp oracle — identical math, XLA-fused.
+``use_pallas=True`` launches the Pallas kernels: compiled (Mosaic) when the
+program is lowered for a TPU, interpreted only when it is lowered for the
+CPU (``repro.kernels.dispatch``); tests check it against the oracle.
 
 Reverse rules: the ops a *forward* integration launches (``alf_midpoint``,
 ``alf_update``) carry closed-form ``jax.custom_vjp`` rules — the step is

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import pallas_call
+
 NEG_INF = -2.0 ** 30
 BLOCK_Q = 256
 BLOCK_KV = 512
@@ -79,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_kv: int,
 def flash_attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, window: int = 0,
                          softcap: float = 0.0, block_q: int = BLOCK_Q,
-                         block_kv: int = BLOCK_KV, interpret: bool = True):
+                         block_kv: int = BLOCK_KV):
     """q: [B, KV, G, Sq, d]; k/v: [B, KV, Sk, d]. Sq % block_q == 0,
     Sk % block_kv == 0 (ops wrapper pads)."""
     b, kh, g, sq, d = q.shape
@@ -103,7 +105,7 @@ def flash_attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash_kernel, block_q=block_q, block_kv=block_kv,
         sm_scale=d ** -0.5, causal=causal, window=window, softcap=softcap,
         seq_kv=sk_real)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -115,6 +117,5 @@ def flash_attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, 1, block_q, d),
                                lambda b_, k_, g_, q_: (b_, k_, g_, q_, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
     )(q, k, v)
     return out[:, :, :, :sq] if pad_q else out

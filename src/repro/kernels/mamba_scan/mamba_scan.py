@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import pallas_call
+
 BLOCK_DI = 256
 
 
@@ -53,10 +55,10 @@ def _scan_kernel(delta_ref, u_ref, a_ref, b_ref, c_ref, h0_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_di", "interpret"))
+                   static_argnames=("block_di",))
 def selective_scan_call(delta: jax.Array, u: jax.Array, A: jax.Array,
                         B: jax.Array, C: jax.Array, h0: jax.Array,
-                        block_di: int = BLOCK_DI, interpret: bool = True):
+                        block_di: int = BLOCK_DI):
     """delta/u: [Bt, S, DI]; A: [DI, ST]; B/C: [Bt, S, ST];
     h0: [Bt, DI, ST]. Returns (y [Bt, S, DI] f32, h_final [Bt, DI, ST] f32).
     DI % block_di == 0 (ops wrapper pads)."""
@@ -67,7 +69,7 @@ def selective_scan_call(delta: jax.Array, u: jax.Array, A: jax.Array,
     grid = (bt, di // block_di)
 
     kernel = functools.partial(_scan_kernel, seq_len=s)
-    y, h_out = pl.pallas_call(
+    y, h_out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -86,6 +88,5 @@ def selective_scan_call(delta: jax.Array, u: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((bt, s, di), jnp.float32),
             jax.ShapeDtypeStruct((bt, di, st), jnp.float32),
         ],
-        interpret=interpret,
     )(delta, u, A[None], B, C, h0)
     return y, h_out

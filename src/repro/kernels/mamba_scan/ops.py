@@ -11,11 +11,11 @@ from . import ref
 from .mamba_scan import BLOCK_DI, selective_scan_call
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
 def selective_scan(delta: jax.Array, u: jax.Array, A: jax.Array,
                    B: jax.Array, C: jax.Array,
                    h0: Optional[jax.Array] = None, *,
-                   use_pallas: bool = False, interpret: bool = True
+                   use_pallas: bool = False
                    ) -> Tuple[jax.Array, jax.Array]:
     """delta/u: [Bt, S, DI]; A: [DI, ST]; B/C: [Bt, S, ST].
     Returns (y [Bt, S, DI] f32, h_final [Bt, DI, ST] f32)."""
@@ -33,7 +33,7 @@ def selective_scan(delta: jax.Array, u: jax.Array, A: jax.Array,
         u = jnp.pad(u, ((0, 0), (0, 0), (0, pad)))
         A = jnp.pad(A, ((0, pad), (0, 0)))
         h0 = jnp.pad(h0, ((0, 0), (0, pad), (0, 0)))
-    y, h = selective_scan_call(delta, u, A, B, C, h0, interpret=interpret)
+    y, h = selective_scan_call(delta, u, A, B, C, h0)
     if pad:
         y = y[..., :di]
         h = h[:, :di]

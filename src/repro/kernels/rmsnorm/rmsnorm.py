@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.dispatch import pallas_call
+
 BLOCK_ROWS = 256
 
 
@@ -24,16 +26,15 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_call(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
-                 block_rows: int = BLOCK_ROWS, interpret: bool = True):
+                 block_rows: int = BLOCK_ROWS):
     rows, d = x.shape
     bs = min(block_rows, rows)
     assert rows % bs == 0
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=(rows // bs,),
         in_specs=[pl.BlockSpec((bs, d), lambda i: (i, 0)),
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((bs, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=interpret,
     )(x, scale)

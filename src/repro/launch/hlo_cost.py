@@ -294,8 +294,14 @@ def _count_pallas(jaxpr) -> int:
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             n += 1
-        for sub in _sub_jaxprs(eqn.params):
-            n += _count_pallas(sub)
+        counts = [_count_pallas(sub) for sub in _sub_jaxprs(eqn.params)]
+        if eqn.primitive.name == "cond":
+            # one branch runs — e.g. the per-platform twins of every kernel
+            # launch (repro.kernels.dispatch: interpreted on CPU, compiled
+            # on TPU) are one launch, not two
+            n += max(counts, default=0)
+        else:
+            n += sum(counts)
     return n
 
 

@@ -35,6 +35,7 @@ and the resolved value is printed in each run's header.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -122,10 +123,15 @@ def mlp_field(rng: np.random.Generator, d_state: int):
     w2 = jnp.asarray(rng.standard_normal((d_state, d_state)) * 0.4,
                      jnp.float32)
     params = {"w1": w1, "w2": w2}
+    # f32 products: the TPU's default precision rounds matmul inputs to
+    # bf16, a 2^-8 relative error in f that the requests' rtol (1e-3)
+    # cannot absorb -- the controller then rejects on noise, runs out of
+    # budget and ends away from the f32 solution.
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
     def f(p, z, t):
-        h = jnp.tanh(z["y"] @ p["w1"])
-        return {"y": z["scale"] * (h @ p["w2"] - z["y"]),
+        h = jnp.tanh(dot(z["y"], p["w1"]))
+        return {"y": z["scale"] * (dot(h, p["w2"]) - z["y"]),
                 "scale": jnp.zeros_like(z["scale"])}
 
     return f, params
@@ -144,7 +150,9 @@ def serve_ode(*, batch: int = 64, d_state: int = 32, t1: float = 1.0,
     between rounds, ``engine='static'`` runs the no-backfill fleet
     baseline. ``rate`` > 0 makes arrivals Poisson at that rate (requests/s
     of serving-clock time); 0 submits everything at t=0 (closed loop).
-    Returns the run's :class:`repro.serve.ServeReport`.
+    Returns ``(report, engine, requests)``: the run's
+    :class:`repro.serve.ServeReport`, the drained engine (served end states
+    in ``engine.results`` keyed by request id) and the submitted requests.
     """
     from repro.core import ALF
     from repro.serve import (ENGINES, EngineConfig, Request, RequestConfig,
@@ -186,7 +194,7 @@ def serve_ode(*, batch: int = 64, d_state: int = 32, t1: float = 1.0,
         eng.submit(requests)
         report = eng.run()
     print(format_report(report))
-    return report
+    return report, eng, requests
 
 
 def main() -> None:
@@ -239,4 +247,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

@@ -111,4 +111,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

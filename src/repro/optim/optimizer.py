@@ -76,7 +76,10 @@ def init_opt_state(cfg: OptimizerConfig, params: Pytree) -> OptState:
     else:
         v = _tm(lambda p: jnp.zeros((0,), jnp.float32), params)
     if cfg.master_dtype:
-        master = _tm(lambda p: p.astype(jnp.dtype(cfg.master_dtype)), params)
+        # a copy even where the dtypes match: the train step donates params
+        # and optimizer state, and one buffer cannot be donated twice
+        master = _tm(lambda p: jnp.array(p, jnp.dtype(cfg.master_dtype),
+                                         copy=True), params)
     else:
         master = _tm(lambda p: jnp.zeros((0,), jnp.float32), params)
     return OptState(jnp.zeros((), jnp.int32), m, v, master)

@@ -37,7 +37,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.checkpoint import AsyncCheckpointer, list_checkpoints
-from repro.configs import get_config, smoke_config
+from repro.configs import ModelConfig, get_config, smoke_config
 from repro.core.ode_block import OdeSettings
 from repro.data.synthetic import DataConfig, make_batch
 from repro.distributed.fault_tolerance import run_with_recovery
@@ -113,13 +113,19 @@ def build(tc: TrainerConfig):
 
 class Trainer:
     """One training run. ``step_hook(step)`` (if given) runs before each
-    step on the host — the fault-injection point for recovery tests."""
+    step on the host — the fault-injection point for recovery tests.
+    ``model_config`` (if given) replaces the model that ``config.arch`` and
+    ``config.smoke`` name, e.g. a published config cut in depth to fit one
+    chip; ``config``'s ODE settings are applied to it."""
 
     def __init__(self, config: TrainerConfig,
                  emitter: Optional[MetricsEmitter] = None,
-                 step_hook: Optional[Callable[[int], None]] = None):
+                 step_hook: Optional[Callable[[int], None]] = None,
+                 model_config: Optional[ModelConfig] = None):
         self.config = config
         self.cfg, self.mesh, self.opt_cfg = build(config)
+        if model_config is not None:
+            self.cfg = model_config.with_ode(config.ode_settings()).validate()
         self.loop = get_train_loop(config.loop)
         self.emitter = emitter if emitter is not None else make_emitter(
             config.emit, config.metrics_path)
@@ -209,7 +215,8 @@ class Trainer:
                         state.params, state.opt, state.ef, batch, cfg=cfg,
                         opt_cfg=opt_cfg, microbatches=tc.microbatches,
                         zero1=zero1)
-                    loss = float(metrics["loss"])   # syncs the step
+                    jax.block_until_ready((p, o))
+                    loss = float(metrics["loss"])
                     if not np.isfinite(loss):
                         raise RuntimeError(f"non-finite loss at step {step}")
                     state = TrainState(p, o, carry,

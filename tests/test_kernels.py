@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True on CPU) vs the
+"""Per-kernel shape/dtype sweeps: Pallas (interpreted on CPU) vs the
 pure-jnp ref.py oracle for every kernel in src/repro/kernels/."""
 import jax
 import jax.numpy as jnp
@@ -280,8 +280,7 @@ def test_flash_attention_vs_ref(case, dtype):
     k = _rand(kk, (b, sk, kv, d), dtype)
     v = _rand(kvk, (b, sk, kv, d), dtype)
     got = fa_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                 softcap=softcap, use_pallas=True,
-                                 interpret=True)
+                                 softcap=softcap, use_pallas=True)
     want = fa_ref.attention_ref(q, k, v, causal=causal, window=window,
                                 softcap=softcap)
     tol = dict(rtol=3e-2, atol=3e-2) if dtype == jnp.bfloat16 \
@@ -297,8 +296,7 @@ def test_flash_attention_rows_sum_to_one_property():
     q = _rand(jax.random.PRNGKey(0), (b, s, h, d), jnp.float32)
     k = _rand(jax.random.PRNGKey(1), (b, s, h, d), jnp.float32)
     v = _rand(jax.random.PRNGKey(2), (b, s, h, d), jnp.float32)
-    out = fa_ops.flash_attention(q, k, v, causal=True, use_pallas=True,
-                                 interpret=True)
+    out = fa_ops.flash_attention(q, k, v, causal=True, use_pallas=True)
     np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(v[:, 0]),
                                rtol=1e-5, atol=1e-5)
 
@@ -355,8 +353,7 @@ def test_mamba_scan_vs_ref(case, dtype):
     A = -jnp.exp(0.3 * jax.random.normal(ks[2], (di, st)))
     B = _rand(ks[3], (bt, s, st), dtype)
     C = _rand(ks[4], (bt, s, st), dtype)
-    y_p, h_p = ms_ops.selective_scan(delta, u, A, B, C, use_pallas=True,
-                                     interpret=True)
+    y_p, h_p = ms_ops.selective_scan(delta, u, A, B, C, use_pallas=True)
     y_r, h_r = ms_ref.selective_scan_ref(delta, u, A, B, C)
     tol = dict(rtol=3e-2, atol=3e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=2e-4, atol=2e-4)

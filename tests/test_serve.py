@@ -422,3 +422,16 @@ class TestServeCLI:
         serve_mod.main()
         out = capsys.readouterr().out
         assert "engine=static" in out and "serve[static]" in out
+
+    def test_ode_field_matmuls_are_f32_on_every_backend(self):
+        # the TPU's default precision would round f's matmul inputs to
+        # bf16, an error the requests' rtol cannot absorb
+        import jax
+        from repro.launch.serve import mlp_field
+        f, params = mlp_field(np.random.default_rng(0), 4)
+        z = {"y": jnp.ones((2, 4)), "scale": jnp.ones((2, 4))}
+        eqns = jax.make_jaxpr(lambda p, z: f(p, z, 0.0))(params, z).eqns
+        dots = [e for e in eqns if e.primitive.name == "dot_general"]
+        assert len(dots) == 2
+        for e in dots:
+            assert e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2

@@ -1,0 +1,65 @@
+"""AOT compiles of the MALI path's Pallas kernels for a described TPU v5e.
+
+Nothing runs: each kernel is lowered through ``ops.py``'s pallas path and
+compiled by the TPU compiler for a v5e chip that is described, not
+attached, at the qwen3-1.7b residual-stream size (4 x 512 x 2048). Mosaic
+refuses here what interpret mode accepts (misaligned blocks, VMEM
+overuse), and the compiled program must hold the kernel
+(``tpu_custom_call``), never the interpreter's plain HLO.
+
+The topology is described inside a module fixture (only one process may
+load the TPU library, so never at import), and the persistent compile
+cache is off around these compiles: a TPU entry written here cannot be
+read back without a chip.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.alf_step import ops
+
+SHAPE = (4, 512, 2048)   # qwen3-1.7b: global_batch 4 x seq_len 512 x d_model
+ETA = 0.9
+
+# name -> (function of n state-shaped arrays and h, n)
+KERNELS = {
+    "alf_midpoint": (lambda z, v, h: ops.alf_midpoint(
+        z, v, h, use_pallas=True), 2),
+    "alf_update": (lambda k1, v, u1, h: ops.alf_update(
+        k1, v, u1, h, eta=ETA, use_pallas=True), 3),
+    "alf_inverse": (lambda zo, vo, u1, h: ops.alf_inverse(
+        zo, vo, u1, h, eta=ETA, use_pallas=True), 3),
+    "alf_bwd_pre": (lambda z, v, az, av, h: ops.alf_bwd_pre(
+        z, v, az, av, h, eta=ETA, use_pallas=True), 4),
+    "alf_bwd_post": (lambda k1, vo, u1, az, av, dk1, h: ops.alf_bwd_post(
+        k1, vo, u1, az, av, dk1, h, eta=ETA, use_pallas=True), 6),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name, dtype):
+    fn, n = KERNELS[name]
+    x = jax.ShapeDtypeStruct(SHAPE, dtype, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(fn).lower(*([x] * n), h).compile()
+    assert "tpu_custom_call" in compiled.as_text()
