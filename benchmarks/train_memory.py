@@ -22,7 +22,6 @@ from repro.core.ode_block import OdeSettings
 from repro.launch.specs import param_specs
 from repro.optim.optimizer import OptimizerConfig, init_opt_state
 from repro.train.loop import train_step
-from repro.train.metrics import ode_residual_bytes
 
 from .common import Row
 
@@ -68,9 +67,6 @@ def run() -> List[Row]:
             series.append(b)
             rows.append((f"train_memory/temp_bytes/{method}/n={n}", b,
                          f"{ARCH} smoke 1-period B={B} S={S}"))
-            rows.append((f"train_memory/residual_bytes/{method}/n={n}",
-                         ode_residual_bytes(_cfg(method, solver, n), B, S),
-                         "analytic Table-1 backward residual"))
         growth = series[-1] / max(series[0], 1)
         rows.append((f"train_memory/growth_{STEPS[0]}to{STEPS[-1]}/{method}",
                      growth,

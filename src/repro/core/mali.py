@@ -224,6 +224,7 @@ def _mali_grid_fwd(cfg, params, z0, ts):
     return out, (params, z_traj, v_traj, res.ts, res.hs, res.n_accepted, ts)
 
 
+@jax.named_scope("mali_backward")
 def _mali_grid_bwd(cfg, res, g):
     g_traj = g[0]  # RunStats cotangents (g[1]) are zero/float0 — ignored.
     params, z_traj, v_traj, seg_ts, seg_hs, seg_acc, ts = res

@@ -423,6 +423,7 @@ def _solve_batched(f, params, z0, t0, t1, solver, controller, gradient,
     return Solution(ys=ys, ts=ts_out, stats=stats)
 
 
+@jax.named_scope("ode")
 def solve(f: Dynamics, params: Pytree, z0: Pytree, t0=0.0, t1=1.0, *,
           solver: Optional[Solver] = None,
           controller: Optional[StepController] = None,

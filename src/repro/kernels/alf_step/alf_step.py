@@ -148,8 +148,9 @@ def _bwd_post_kernel(h_ref, k1_ref, vo_ref, u1_ref, az_ref, av_ref, dk1_ref,
                    + (1.0 - 2.0 * eta) * cot_vout).astype(dv_ref.dtype)
 
 
-def _local_call(kernel, h, arrays, n_out, block_rows):
-    """One launch over [rows, LANES] arrays on one device."""
+def _local_call(kernel, name, h, arrays, n_out, block_rows):
+    """One launch of the kernel ``name`` over [rows, LANES] arrays on one
+    device."""
     rows = arrays[0].shape[0]
     bs = min(block_rows, rows)
     # Pad rows to a block multiple: an unguarded `rows // bs` grid covers
@@ -167,6 +168,7 @@ def _local_call(kernel, h, arrays, n_out, block_rows):
         for a in arrays[:n_out])
     fn = pallas_call(
         kernel,
+        name=name,
         grid=grid,
         in_specs=([pl.BlockSpec(memory_space=pltpu.SMEM)]
                   + [spec] * len(arrays)),
@@ -195,13 +197,15 @@ def _row_mesh():
     return mesh
 
 
-def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS):
+@jax.named_scope("alf_kernel")
+def _tiled_call(kernel, name, args, n_out, block_rows=BLOCK_ROWS):
     """args: (h_scalar, *arrays) with arrays pre-shaped [rows, LANES].
 
     Under a multi-device mesh the launch is shard-local: GSPMD cannot
     partition a Mosaic kernel, and every op here is elementwise, so each
     device runs the kernel on its own slice of rows (a shard_map over all
     mesh axes, rows zero-padded to a multiple of the device count).
+    Every call, its padding included, runs under the ``alf_kernel`` scope.
     """
     h, *arrays = args
     # h rides at >= f32 whatever the block storage dtype (a bf16 h would
@@ -210,14 +214,15 @@ def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS):
     h = h.reshape(1, 1)
     mesh = _row_mesh()
     if mesh is None:
-        return _local_call(kernel, h, arrays, n_out, block_rows)
+        return _local_call(kernel, name, h, arrays, n_out, block_rows)
     rows = arrays[0].shape[0]
     pad = (-rows) % mesh.size
     if pad:
         arrays = [jnp.pad(a, ((0, pad), (0, 0))) for a in arrays]
     rows_spec = P(tuple(mesh.axis_names))
     out = jax.shard_map(
-        lambda h_, *a: _local_call(kernel, h_, list(a), n_out, block_rows),
+        lambda h_, *a: _local_call(kernel, name, h_, list(a), n_out,
+                                   block_rows),
         mesh=mesh, in_specs=(P(),) + (rows_spec,) * len(arrays),
         out_specs=(rows_spec,) * n_out if n_out > 1 else rows_spec,
         check_vma=False)(h, *arrays)
@@ -226,40 +231,42 @@ def _tiled_call(kernel, args, n_out, block_rows=BLOCK_ROWS):
 
 def midpoint_call(z, v, h, *, sign=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_midpoint_kernel, sign=sign),
-                       (h, z, v), 1, block_rows)
+                       "alf_midpoint", (h, z, v), 1, block_rows)
 
 
 def update_call(k1, v, u1, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_update_kernel, eta=eta),
-                       (h, k1, v, u1), 2, block_rows)
+                       "alf_update", (h, k1, v, u1), 2, block_rows)
 
 
 def inverse_update_call(k1, v_out, u1, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_inverse_update_kernel, eta=eta),
-                       (h, k1, v_out, u1), 2, block_rows)
+                       "alf_inverse_update", (h, k1, v_out, u1), 2,
+                       block_rows)
 
 
 def inverse_call(z_out, v_out, u1, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_inverse_kernel, eta=eta),
-                       (h, z_out, v_out, u1), 2, block_rows)
+                       "alf_inverse", (h, z_out, v_out, u1), 2, block_rows)
 
 
 def midpoint_vjp_call(g, h, *, sign=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_midpoint_vjp_kernel, sign=sign),
-                       (h, g), 1, block_rows)
+                       "alf_midpoint_vjp", (h, g), 1, block_rows)
 
 
 def update_vjp_call(g_z, g_v, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_update_vjp_kernel, eta=eta),
-                       (h, g_z, g_v), 2, block_rows)
+                       "alf_update_vjp", (h, g_z, g_v), 2, block_rows)
 
 
 def bwd_pre_call(z, v, a_z, a_v, h, *, eta=1.0, block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_bwd_pre_kernel, eta=eta),
-                       (h, z, v, a_z, a_v), 2, block_rows)
+                       "alf_bwd_pre", (h, z, v, a_z, a_v), 2, block_rows)
 
 
 def bwd_post_call(k1, v_out, u1, a_z, a_v, dk1, h, *, eta=1.0,
                   block_rows=BLOCK_ROWS):
     return _tiled_call(functools.partial(_bwd_post_kernel, eta=eta),
-                       (h, k1, v_out, u1, a_z, a_v, dk1), 4, block_rows)
+                       "alf_bwd_post", (h, k1, v_out, u1, a_z, a_v, dk1), 4,
+                       block_rows)

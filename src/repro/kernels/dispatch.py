@@ -8,6 +8,10 @@ lowered for the TPU (run there, or AOT-compiled here for a described chip)
 holds only the Mosaic kernel (``tpu_custom_call`` in its HLO); a program
 lowered for the CPU holds only the interpreter's plain HLO. No caller can
 leave a TPU program in interpret mode by forgetting an argument.
+
+Every kernel is named (``name=``, required): the name is the Mosaic
+kernel's ``kernel_name``, so a kernel keeps it in the compiled program and
+in a profile, whatever the surrounding code is called.
 """
 from __future__ import annotations
 
@@ -15,13 +19,13 @@ import jax
 from jax.experimental import pallas as pl
 
 
-def pallas_call(kernel, **spec):
-    """``pl.pallas_call(kernel, **spec)`` with ``interpret`` chosen per
-    lowering platform (CPU: interpret; TPU: compiled)."""
+def pallas_call(kernel, *, name: str, **spec):
+    """``pl.pallas_call(kernel, name=name, **spec)`` with ``interpret``
+    chosen per lowering platform (CPU: interpret; TPU: compiled)."""
     interpreted = pl.pallas_call(  # odelint: disable=R003 -- grid in **spec
-        kernel, interpret=True, **spec)
+        kernel, interpret=True, name=name, **spec)
     compiled = pl.pallas_call(  # odelint: disable=R003 -- grid in **spec
-        kernel, interpret=False, **spec)
+        kernel, interpret=False, name=name, **spec)
 
     def run(*args):
         return jax.lax.platform_dependent(*args, cpu=interpreted,

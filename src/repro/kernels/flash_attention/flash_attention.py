@@ -107,6 +107,7 @@ def flash_attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
         seq_kv=sk_real)
     out = pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, block_q, d),

@@ -71,6 +71,7 @@ def selective_scan_call(delta: jax.Array, u: jax.Array, A: jax.Array,
     kernel = functools.partial(_scan_kernel, seq_len=s)
     y, h_out = pallas_call(
         kernel,
+        name="mamba_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, s, block_di), lambda b, i: (b, 0, i)),   # delta

@@ -32,6 +32,7 @@ def rmsnorm_call(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
     assert rows % bs == 0
     return pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
+        name="rmsnorm",
         grid=(rows // bs,),
         in_specs=[pl.BlockSpec((bs, d), lambda i: (i, 0)),
                   pl.BlockSpec((d,), lambda i: (0,))],

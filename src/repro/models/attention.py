@@ -339,6 +339,7 @@ def _finish(params, b, s, out):
     return out.reshape(b, s, -1) @ params["wo"]
 
 
+@jax.named_scope("attention")
 def attention_train(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
                     x: jax.Array, positions: jax.Array = None) -> jax.Array:
     b, s, _ = x.shape
@@ -370,6 +371,7 @@ class KVCache(NamedTuple):
         return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
 
 
+@jax.named_scope("attention")
 def attention_prefill(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
                       x: jax.Array, positions: jax.Array, cache: KVCache,
                       slot) -> Tuple[jax.Array, KVCache]:
@@ -389,6 +391,7 @@ def attention_prefill(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
     return _finish(params, b, s, out), cache
 
 
+@jax.named_scope("attention")
 def attention_decode(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
                      x: jax.Array, pos: jax.Array, cache: KVCache,
                      slot) -> Tuple[jax.Array, KVCache]:

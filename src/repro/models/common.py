@@ -36,6 +36,7 @@ def rmsnorm_init(d: int, dtype) -> Pytree:
     return {"scale": jnp.ones((d,), dtype)}
 
 
+@jax.named_scope("norm")
 def rmsnorm(params: Pytree, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     dtype = x.dtype
     xf = x.astype(jnp.float32)

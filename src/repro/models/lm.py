@@ -47,6 +47,7 @@ def _head_matrix(params: Pytree, cfg: ModelConfig) -> jax.Array:
     return params["head"]
 
 
+@jax.named_scope("embed")
 def _embed(params: Pytree, cfg: ModelConfig, batch: Pytree) -> jax.Array:
     if cfg.input_mode == "embeds":
         return batch["embeds"].astype(jnp.dtype(cfg.compute_dtype))
@@ -101,7 +102,9 @@ def lm_loss_and_stats(params: Pytree, cfg: ModelConfig, batch: Pytree
     tangent machinery (R002c).
     """
     h, stats = backbone_train(params, cfg, batch)
-    loss = chunked_ce_loss(h, _head_matrix(params, cfg), batch["labels"], cfg)
+    with jax.named_scope("head_loss"):
+        loss = chunked_ce_loss(h, _head_matrix(params, cfg), batch["labels"],
+                               cfg)
     return loss, stats
 
 

@@ -23,5 +23,6 @@ def init_mlp(key: jax.Array, cfg: ModelConfig, d_ff: int) -> Pytree:
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params: Pytree, x: jax.Array) -> jax.Array:
     return (silu(x @ params["w_gate"]) * (x @ params["w_up"])) @ params["w_down"]

@@ -85,6 +85,7 @@ def init_opt_state(cfg: OptimizerConfig, params: Pytree) -> OptState:
     return OptState(jnp.zeros((), jnp.int32), m, v, master)
 
 
+@jax.named_scope("optimizer")
 def apply_updates(cfg: OptimizerConfig, params: Pytree, grads: Pytree,
                   state: OptState) -> Tuple[Pytree, OptState, dict]:
     """One optimizer step; returns (new_params, new_state, metrics)."""

@@ -15,8 +15,7 @@ and structured telemetry (:mod:`repro.train.metrics`).
 from .loop import (TRAIN_LOOPS, CompressedLoop, StandardLoop, TrainLoop,
                    get_train_loop, loss_and_grads, train_step)
 from .metrics import (EMITTERS, JsonlEmitter, MemoryEmitter, MetricsEmitter,
-                      StdoutEmitter, StepRecord, make_emitter,
-                      ode_residual_bytes)
+                      StdoutEmitter, StepRecord, make_emitter)
 from .state import (ConfigMismatchError, TrainState, config_fingerprint,
                     restore_train_state, state_tree)
 from .trainer import Trainer, TrainerConfig
@@ -26,7 +25,7 @@ __all__ = [
     "TrainLoop", "StandardLoop", "CompressedLoop", "TRAIN_LOOPS",
     "get_train_loop", "loss_and_grads", "train_step",
     "StepRecord", "MetricsEmitter", "StdoutEmitter", "JsonlEmitter",
-    "MemoryEmitter", "EMITTERS", "make_emitter", "ode_residual_bytes",
+    "MemoryEmitter", "EMITTERS", "make_emitter",
     "TrainState", "ConfigMismatchError", "config_fingerprint",
     "restore_train_state", "state_tree",
 ]

@@ -1,13 +1,11 @@
 """Structured per-step training telemetry.
 
 A :class:`StepRecord` is one row of the run's metrics table: optimizer
-scalars (loss, lr, grad-norm), wall time, and the continuous-depth
-accounting — dynamics evaluations and accepted/rejected trials from the
-step's ``solve()`` calls (threaded out of the jitted step as RunStats
-aux), the analytic MALI backward-residual footprint
-(:func:`ode_residual_bytes` — the paper's O(1)-in-steps memory claim as a
-number), and the pallas kernel launches per step
-(``launch.hlo_cost.count_pallas_launches``, counted once at trace time).
+scalars (loss, lr, grad-norm), the step's host time and the seconds of its
+host phases (the ``train.*`` spans of :mod:`repro.train.spans`), the
+backend compiles it triggered, and the continuous-depth accounting —
+dynamics evaluations and accepted/rejected trials from the step's
+``solve()`` calls (threaded out of the jitted step as RunStats aux).
 
 :class:`MetricsEmitter` is the registered sink axis (R004): stdout JSON
 lines, a JSONL file, or an in-memory list for tests.
@@ -18,11 +16,6 @@ import dataclasses
 import json
 from typing import Dict, List, Type
 
-import jax
-import jax.numpy as jnp
-
-from repro.configs.base import ModelConfig
-
 
 @dataclasses.dataclass(frozen=True)
 class StepRecord:
@@ -31,37 +24,18 @@ class StepRecord:
     loss: float
     lr: float
     grad_norm: float
-    wall_s: float           # wall time of this step (s)
+    wall_s: float           # host time from the step's start to its record
+    batch_s: float          # make the batch and place it on the devices
+    dispatch_s: float       # enqueue the jitted step
+    wait_s: float           # block until its outputs are ready
+    readback_s: float       # read its metrics back to the host
+    compiles: int           # backend compiles during the step
     fevals: int             # dynamics evaluations across the step's solves
     accepted: int           # accepted solver trials
     rejected: int           # rejected solver trials
-    residual_bytes: int     # analytic backward-residual footprint (static)
-    pallas_launches: int    # pallas_call count in the step's jaxpr (static)
 
     def as_row(self) -> Dict:
         return dataclasses.asdict(self)
-
-
-def ode_residual_bytes(cfg: ModelConfig, batch_size: int,
-                       seq_len: int) -> int:
-    """Analytic backward-residual bytes of one train step's solves.
-
-    Per residual branch this is the gradient method's
-    ``residual_bytes(z0, n_obs, solver, controller)`` — for MALI the
-    per-observation (z, v) pairs, constant in step count; for Naive/ACA it
-    grows with the step budget (paper Table 1) — times the number of ODE
-    branches in the unrolled depth. Static shapes only; 0 with
-    ``ode.mode='off'``.
-    """
-    if cfg.ode.mode == "off":
-        return 0
-    solver, controller, gradient, _ = cfg.ode.as_objects()
-    z0 = jax.ShapeDtypeStruct((batch_size, seq_len, cfg.d_model),
-                              jnp.float32)
-    n_obs = 2 if cfg.ode.obs_times is None else len(cfg.ode.obs_times)
-    per = gradient.residual_bytes(z0, n_obs, solver, controller)
-    branches = sum(1 + (spec.mlp != "none") for spec in cfg.layers())
-    return per * branches
 
 
 class MetricsEmitter:

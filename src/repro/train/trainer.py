@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 from typing import Callable, Dict, Optional
 
 import jax
@@ -43,13 +42,12 @@ from repro.data.synthetic import DataConfig, make_batch
 from repro.distributed.fault_tolerance import run_with_recovery
 from repro.distributed.sharding import (batch_shardings, opt_state_shardings,
                                         param_shardings, replicated)
-from repro.launch.hlo_cost import count_pallas_launches
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import init_lm
 from repro.optim.optimizer import OptimizerConfig, OptState, init_opt_state
-from repro.train.loop import get_train_loop, train_step
-from repro.train.metrics import (MetricsEmitter, StepRecord, make_emitter,
-                                 ode_residual_bytes)
+from repro.train.loop import get_train_loop
+from repro.train.metrics import MetricsEmitter, StepRecord, make_emitter
+from repro.train.spans import CompileCounter, StepSpans
 from repro.train.state import (TrainState, config_fingerprint,
                                restore_train_state, state_tree)
 
@@ -113,7 +111,8 @@ def build(tc: TrainerConfig):
 
 class Trainer:
     """One training run. ``step_hook(step)`` (if given) runs before each
-    step on the host — the fault-injection point for recovery tests.
+    step on the host, outside the step's spans — the fault-injection
+    point for recovery tests.
     ``model_config`` (if given) replaces the model that ``config.arch`` and
     ``config.smoke`` name, e.g. a published config cut in depth to fit one
     chip; ``config``'s ODE settings are applied to it."""
@@ -131,7 +130,6 @@ class Trainer:
             config.emit, config.metrics_path)
         self.step_hook = step_hook
         self.records: Dict[int, StepRecord] = {}
-        self.pallas_launches = 0
         self._state: Optional[TrainState] = None
 
     @property
@@ -156,11 +154,8 @@ class Trainer:
             global_batch=tc.global_batch, seq_len=tc.seq_len)
         ckpt = (AsyncCheckpointer(tc.ckpt_dir, keep=tc.keep)
                 if tc.ckpt_dir else None)
-        residual_bytes = ode_residual_bytes(
-            cfg, tc.global_batch // max(tc.microbatches, 1), tc.seq_len)
-        compress = self.loop.name == "compressed"
 
-        with mesh:
+        with mesh, CompileCounter() as compiles:
             params = init_lm(jax.random.PRNGKey(tc.seed), cfg)
             p_sh = param_shardings(cfg, mesh, params)
             o_sh = OptState(replicated(mesh),
@@ -186,14 +181,48 @@ class Trainer:
                 return {k: jax.device_put(v, b_sh[k])
                         for k, v in batch.items()}
 
-            batch0 = put_batch(0)
-            carry0 = state.ef
-            self.pallas_launches = count_pallas_launches(
-                lambda p, o, b: train_step(
-                    p, o, carry0, b, cfg=cfg, opt_cfg=opt_cfg,
-                    microbatches=tc.microbatches, compress=compress,
-                    zero1=False),
-                state.params, state.opt, batch0)
+            def one_step(step: int, state: TrainState,
+                         spans: StepSpans) -> TrainState:
+                with spans.phase("batch"):
+                    batch = put_batch(step)
+                with spans.phase("dispatch"):
+                    p, o, carry, metrics = self.loop.step(
+                        state.params, state.opt, state.ef, batch, cfg=cfg,
+                        opt_cfg=opt_cfg, microbatches=tc.microbatches,
+                        zero1=zero1)
+                with spans.phase("wait"):
+                    jax.block_until_ready((p, o))
+                with spans.phase("readback"):
+                    loss = float(metrics["loss"])
+                    if not np.isfinite(loss):
+                        raise RuntimeError(f"non-finite loss at step {step}")
+                    lr = float(metrics["lr"])
+                    grad_norm = float(metrics["grad_norm"])
+                    fevals, accepted, rejected = (int(metrics[k]) for k in (
+                        "ode_fevals", "ode_accepted", "ode_rejected"))
+                wall_s = spans.wall_s()
+                with spans.phase("record"):
+                    state = TrainState(p, o, carry,
+                                       jax.random.fold_in(state.rng, step))
+                    sec = spans.seconds
+                    rec = StepRecord(
+                        step=step, loss=loss, lr=lr, grad_norm=grad_norm,
+                        wall_s=wall_s, batch_s=sec["batch"],
+                        dispatch_s=sec["dispatch"], wait_s=sec["wait"],
+                        readback_s=sec["readback"],
+                        compiles=spans.compiles, fevals=fevals,
+                        accepted=accepted, rejected=rejected)
+                    self.records[step] = rec
+                    self.emitter.emit(rec)
+                    if step % tc.log_every == 0 or step == tc.steps - 1:
+                        log.info("step %d loss %.4f lr %.2e gnorm %.2f "
+                                 "fevals %d", step, loss, rec.lr,
+                                 rec.grad_norm, rec.fevals)
+                if ckpt is not None and (step + 1) % tc.ckpt_every == 0:
+                    with spans.phase("ckpt"):
+                        ckpt.save(step + 1, state_tree(state),
+                                  metadata={**fingerprint, "loss": loss})
+                return state
 
             def train_loop(resume: Optional[int]) -> int:
                 nonlocal state
@@ -209,36 +238,8 @@ class Trainer:
                 for step in range(start, tc.steps):
                     if self.step_hook is not None:
                         self.step_hook(step)
-                    t0 = time.time()
-                    batch = put_batch(step) if step else batch0
-                    p, o, carry, metrics = self.loop.step(
-                        state.params, state.opt, state.ef, batch, cfg=cfg,
-                        opt_cfg=opt_cfg, microbatches=tc.microbatches,
-                        zero1=zero1)
-                    jax.block_until_ready((p, o))
-                    loss = float(metrics["loss"])
-                    if not np.isfinite(loss):
-                        raise RuntimeError(f"non-finite loss at step {step}")
-                    state = TrainState(p, o, carry,
-                                       jax.random.fold_in(state.rng, step))
-                    rec = StepRecord(
-                        step=step, loss=loss, lr=float(metrics["lr"]),
-                        grad_norm=float(metrics["grad_norm"]),
-                        wall_s=time.time() - t0,
-                        fevals=int(metrics["ode_fevals"]),
-                        accepted=int(metrics["ode_accepted"]),
-                        rejected=int(metrics["ode_rejected"]),
-                        residual_bytes=residual_bytes,
-                        pallas_launches=self.pallas_launches)
-                    self.records[step] = rec
-                    self.emitter.emit(rec)
-                    if step % tc.log_every == 0 or step == tc.steps - 1:
-                        log.info("step %d loss %.4f lr %.2e gnorm %.2f "
-                                 "fevals %d", step, loss, rec.lr,
-                                 rec.grad_norm, rec.fevals)
-                    if ckpt is not None and (step + 1) % tc.ckpt_every == 0:
-                        ckpt.save(step + 1, state_tree(state),
-                                  metadata={**fingerprint, "loss": loss})
+                    with StepSpans(step, compiles) as spans:
+                        state = one_step(step, state, spans)
                 return tc.steps
 
             def restore_step() -> Optional[int]:

@@ -12,6 +12,8 @@ load the TPU library, so never at import), and the persistent compile
 cache is off around these compiles: a TPU entry written here cannot be
 read back without a chip.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -63,3 +65,16 @@ def test_kernel_compiles_for_v5e(one_chip, name, dtype):
     h = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
     compiled = jax.jit(fn).lower(*([x] * n), h).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_keeps_its_name_for_v5e(one_chip, name):
+    """The compiled program names the Mosaic call after its kernel, so a
+    profile shows it under that name."""
+    fn, n = KERNELS[name]
+    x = jax.ShapeDtypeStruct(SHAPE, jnp.float32, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    text = jax.jit(fn).lower(*([x] * n), h).compile().as_text()
+    calls = re.findall(
+        r'%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text)
+    assert calls and all(re.fullmatch(rf"{name}(\.\d+)?", c) for c in calls)
