@@ -3,9 +3,10 @@
 Three execution paths:
   * ``attention_train`` — full-sequence causal attention. Short sequences use
     the direct einsum; long sequences use a flash-style chunked online-softmax
-    (pure-jnp ``lax.scan`` over query/KV blocks: O(S * block) memory, lowers
-    on any backend). The Pallas TPU kernel (repro.kernels.flash_attention)
-    implements the same contraction for the hot path.
+    (pure-jnp loops over query/KV blocks: O(S * block) memory, lowers on any
+    backend) that visits, for each query block, only the KV blocks the
+    causal / sliding-window mask leaves live. The Pallas TPU kernel
+    (repro.kernels.flash_attention) implements the same contraction.
   * ``attention_prefill`` — train path + writes K/V into the cache slot.
   * ``attention_decode`` — single-token query against the cache.
 
@@ -203,17 +204,46 @@ def _chunk_arrays(cfg, q, k, v, q_pos, k_pos, block_q, block_kv,
             kpos.reshape(nkv, block_kv), nq, nkv, pad_q, pad_kv, g)
 
 
+def _live_kv_range(qpos: jax.Array, kpos: jax.Array, window: int):
+    """Per q-block, the kv-block range ``[lo, hi]`` that holds every live tile.
+
+    Tile (i, j) can hold an unmasked pair only if ``min(kpos[j]) <=
+    max(qpos[i])`` (causal) and, with ``window > 0``, ``max(kpos[j]) >
+    min(qpos[i]) - window``; every tile outside the range is masked for every
+    row of the q-block. Read from the tiled positions (``qpos [nq, bq]``,
+    ``kpos [nkv, bk]``, padding included), so it holds for any positions.
+    A q-block with no live tile gets ``lo > hi``. Returns int32 ``[nq]`` each.
+    """
+    nkv = kpos.shape[0]
+    live = kpos.min(axis=1)[None, :] <= qpos.max(axis=1)[:, None]
+    if window > 0:
+        live &= (kpos.max(axis=1)[None, :]
+                 > qpos.min(axis=1)[:, None] - window)
+    j = jnp.arange(nkv, dtype=jnp.int32)
+    lo = jnp.min(jnp.where(live, j, nkv), axis=1)
+    hi = jnp.max(jnp.where(live, j, -1), axis=1)
+    return lo, hi
+
+
 @functools.lru_cache(maxsize=None)
 def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
                      block_q: int, block_kv: int):
     """FlashAttention-2-style custom_vjp over pre-tiled inputs.
 
+    Each q-block visits only the kv-blocks of its live range
+    (``_live_kv_range``), in ascending order, forward and backward. A
+    skipped tile is masked for every row of the q-block: after a row's first
+    live tile it would add ``p = 0``, and before it what it added is wiped by
+    ``alpha = 0``. So every row with a live key (padding rows are cut off
+    by the caller) gets what the sweep over every tile gives.
+
     Forward: online softmax, residuals = (tiles, out, lse) — O(S*d), no
     O(S^2) tiles survive to the backward (the vanilla AD-of-scan backward
     stacks the per-tile f32 probabilities: measured 2.1 GB/layer residual at
     stablelm train_4k; EXPERIMENTS.md §Perf iteration 2).
-    Backward: recompute each (q-block, kv-block) tile from (q,k,v,lse),
-    accumulate dq/dk/dv — standard FA2, incl. the softcap chain rule.
+    Backward: recompute each live (q-block, kv-block) tile from
+    (q,k,v,lse), accumulate dq and add dk/dv into kv-block j of the carried
+    dk/dv in place — standard FA2, incl. the softcap chain rule.
 
     Tiled layouts: q [nq, B, bq, K, G, dh]; k/v [nkv, B, bk, K, dh];
     qpos [nq, bq]; kpos [nkv, bk]. Returns out [nq, B, K, G, bq, dh].
@@ -227,36 +257,40 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
         s = softcap(s, softcap_val)
         return s + _bias(qpb, kposb)
 
+    def _tile(x, j):
+        return lax.dynamic_index_in_dim(x, j, 0, keepdims=False)
+
     def forward(qp, kp, vp, qpos, kpos):
+        lo, hi = _live_kv_range(qpos, kpos, window)
+
         def q_block(carry, xs):
-            qb, qpb = xs
+            qb, qpb, lo_i, hi_i = xs
             qb = qb.astype(jnp.float32)
 
-            def kv_step(c, kxs):
+            def kv_step(j, c):
                 m, l, acc = c
-                kb, vb, kposb = kxs
-                s = _scores(qb, kb.astype(jnp.float32), qpb, kposb)
+                s = _scores(qb, _tile(kp, j).astype(jnp.float32), qpb,
+                            _tile(kpos, j))
                 m_new = jnp.maximum(m, s.max(axis=-1))
                 p = jnp.exp(s - m_new[..., None])
                 alpha = jnp.exp(m - m_new)
                 l_new = l * alpha + p.sum(axis=-1)
                 acc_new = acc * alpha[..., None] + jnp.einsum(
-                    "bkgqs,bskd->bkgqd", p, vb.astype(jnp.float32))
-                return (m_new, l_new, acc_new), None
+                    "bkgqs,bskd->bkgqd", p, _tile(vp, j).astype(jnp.float32))
+                return m_new, l_new, acc_new
 
             b, bq, kh, g, dh = qb.shape
             m0 = jnp.full((b, kh, g, bq), NEG_INF, jnp.float32)
             l0 = jnp.zeros((b, kh, g, bq), jnp.float32)
             a0 = jnp.zeros((b, kh, g, bq, dh), jnp.float32)
-            (m, l, acc), _ys = lax.scan(kv_step, (m0, l0, a0),
-                                        (kp, vp, kpos))
+            m, l, acc = lax.fori_loop(lo_i, hi_i + 1, kv_step, (m0, l0, a0))
             out = acc / jnp.maximum(l, 1e-30)[..., None]
             # +inf lse for fully-masked (padding) rows => p == 0 in bwd
             lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)),
                             jnp.inf)
             return carry, (out, lse)
 
-        _, (outs, lses) = lax.scan(q_block, 0, (qp, qpos))
+        _, (outs, lses) = lax.scan(q_block, 0, (qp, qpos, lo, hi))
         return outs, lses  # [nq,B,K,G,bq,dh], [nq,B,K,G,bq]
 
     @jax.custom_vjp
@@ -269,26 +303,23 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
 
     def flash_bwd(res, g_out):
         qp, kp, vp, qpos, kpos, outs, lses = res
-        nkv = kp.shape[0]
-        b, bk, kh, dh = kp.shape[1:]
+        lo, hi = _live_kv_range(qpos, kpos, window)
         # delta_i = sum_d dO_i * O_i  (FA2)
         delta = jnp.sum(g_out.astype(jnp.float32)
                         * outs.astype(jnp.float32), axis=-1)  # [nq,B,K,G,bq]
 
         def q_block(carry, xs):
-            dk_all, dv_all = carry
-            qb, dob, lseb, deltab, qpb = xs
+            qb, dob, lseb, deltab, qpb, lo_i, hi_i = xs
             qb = qb.astype(jnp.float32)
             dob = dob.astype(jnp.float32)
 
-            def kv_step(c, kxs):
-                dq_b, = c
-                kb, vb, kposb, j = kxs
-                kb = kb.astype(jnp.float32)
-                vb = vb.astype(jnp.float32)
+            def kv_step(j, c):
+                dq_b, dk, dv = c
+                kb = _tile(kp, j).astype(jnp.float32)
+                vb = _tile(vp, j).astype(jnp.float32)
                 s_raw = jnp.einsum("bqkgd,bskd->bkgqs", qb, kb) * scale
                 s_c = softcap(s_raw, softcap_val)
-                s_b = s_c + _bias(qpb, kposb)
+                s_b = s_c + _bias(qpb, _tile(kpos, j))
                 p = jnp.exp(s_b - lseb[..., None])           # [b,k,g,bq,bk]
                 dv_t = jnp.einsum("bkgqs,bkgqd->bskd", p, dob)
                 dp = jnp.einsum("bkgqd,bskd->bkgqs", dob, vb)
@@ -300,18 +331,21 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
                 ds = ds * scale
                 dq_b = dq_b + jnp.einsum("bkgqs,bskd->bqkgd", ds, kb)
                 dk_t = jnp.einsum("bkgqs,bqkgd->bskd", ds, qb)
-                return (dq_b,), (dk_t, dv_t)
+                dk = lax.dynamic_update_index_in_dim(
+                    dk, _tile(dk, j) + dk_t, j, 0)
+                dv = lax.dynamic_update_index_in_dim(
+                    dv, _tile(dv, j) + dv_t, j, 0)
+                return dq_b, dk, dv
 
             dq0 = jnp.zeros(qb.shape, jnp.float32)
-            (dq_b,), (dk_ts, dv_ts) = lax.scan(
-                kv_step, (dq0,),
-                (kp, vp, kpos, jnp.arange(nkv, dtype=jnp.int32)))
-            return (dk_all + dk_ts, dv_all + dv_ts), dq_b
+            dq_b, dk, dv = lax.fori_loop(lo_i, hi_i + 1, kv_step,
+                                         (dq0,) + carry)
+            return (dk, dv), dq_b
 
         dk0 = jnp.zeros(kp.shape, jnp.float32)
         dv0 = jnp.zeros(vp.shape, jnp.float32)
         (dk, dv), dqs = lax.scan(q_block, (dk0, dv0),
-                                 (qp, g_out, lses, delta, qpos))
+                                 (qp, g_out, lses, delta, qpos, lo, hi))
         return (dqs.astype(qp.dtype), dk.astype(kp.dtype),
                 dv.astype(vp.dtype), None, None)
 

@@ -42,6 +42,80 @@ def test_flash_bwd_matches_ad_reference(window, softcap):
                                    rtol=2e-3, atol=2e-3)
 
 
+def _rel(got, want):
+    """Norm-wise relative gap ||got - want|| / ||want||."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+# (s, heads, kv_heads, block_q, block_kv, window, softcap)
+LIVE_TILE_CASES = {
+    "causal": (512, 4, 4, 64, 128, 0, 0.0),
+    "window-inside-one-kv-block": (512, 4, 4, 64, 128, 40, 0.0),
+    "window-spans-two-kv-blocks": (512, 4, 4, 64, 128, 200, 0.0),
+    "padded-q-and-kv": (300, 4, 4, 64, 128, 0, 0.0),
+    "gqa": (512, 4, 2, 64, 128, 0, 0.0),
+    "softcap": (512, 4, 2, 64, 128, 0, 30.0),
+    # a q-block wider than window + kv-block: its last rows meet masked
+    # tiles of the live range before their first live one (alpha = 0)
+    "masked-tile-before-first-live": (256, 4, 2, 128, 32, 40, 0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_TILE_CASES))
+def test_flash_live_tiles_match_full_sweep(case):
+    """The flash path, which visits only live tiles, equals the chunked
+    path's AD through every tile: output and dq/dk/dv within 1e-6."""
+    s, h, kv, bq, bk, window, cap = LIVE_TILE_CASES[case]
+    cfg = dataclasses.replace(smoke_config("gemma2-2b"), attn_softcap=cap)
+    b, dh = 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (b, s, h, dh))
+    k = jax.random.normal(ks[1], (b, s, kv, dh))
+    v = jax.random.normal(ks[2], (b, s, kv, dh))
+    w = jax.random.normal(ks[3], (b, s, h, dh))
+    pos = jnp.arange(s, dtype=jnp.int32)
+
+    def attn(fn):
+        return lambda q, k, v: fn(cfg, q, k, v, pos, pos, window,
+                                  block_q=bq, block_kv=bk)
+
+    live, full = attn(A._sdpa_chunked_flash), attn(A._sdpa_chunked)
+    assert _rel(live(q, k, v), full(q, k, v)) <= 1e-6
+    grads = [jax.grad(lambda *a, f=f: (f(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v) for f in (live, full)]
+    for got, want in zip(*grads):
+        assert _rel(got, want) <= 1e-6
+
+
+def _tiled_pos(s, bq, bk):
+    """The padded, tiled positions the chunked paths give the flash core."""
+    x = jnp.zeros((1, s, 1, 1))
+    pos = jnp.arange(s, dtype=jnp.int32)
+    tiled = A._chunk_arrays(None, x, x, x, pos, pos, bq, bk)
+    return tiled[3], tiled[4]
+
+
+@pytest.mark.parametrize("s, bq, bk, window, lo, hi", [
+    # the training cells' shape: 20 of 32 tiles
+    (4096, 512, 1024, 0, [0] * 8, [0, 0, 1, 1, 2, 2, 3, 3]),
+    # q-block i sees kv-blocks i-2..i (256(i-2)+255 > 256i-300)
+    (1024, 256, 256, 300, [0, 0, 0, 1], [0, 1, 2, 3]),
+    # 300 rows: the last q-block holds rows 256..299 and padding
+    (300, 64, 128, 0, [0] * 5, [0, 0, 1, 1, 2]),
+], ids=["s4096-causal", "window", "padded"])
+def test_live_kv_range(s, bq, bk, window, lo, hi):
+    qpos, kpos = _tiled_pos(s, bq, bk)
+    got_lo, got_hi = A._live_kv_range(qpos, kpos, window)
+    np.testing.assert_array_equal(np.asarray(got_lo), lo)
+    np.testing.assert_array_equal(np.asarray(got_hi), hi)
+    real = np.asarray(qpos.max(axis=1)) >= 0
+    assert np.all(np.asarray(got_lo)[real] <= np.asarray(got_hi)[real])
+    if s == 4096:
+        assert int((got_hi - got_lo + 1).sum()) == 20
+        assert qpos.shape[0] * kpos.shape[0] == 32
+
+
 def test_flash_vs_direct_small():
     """Chunked (flash) path == direct softmax attention."""
     cfg = smoke_config("qwen3-1.7b")

@@ -1,4 +1,5 @@
-"""AOT compiles of the MALI path's Pallas kernels for a described TPU v5e.
+"""AOT compiles of the MALI path's Pallas kernels, and of the training
+path's chunked attention, for a described TPU v5e.
 
 Nothing runs: each kernel is lowered through ``ops.py``'s pallas path and
 compiled by the TPU compiler for a v5e chip that is described, not
@@ -19,7 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import smoke_config
 from repro.kernels.alf_step import ops
+from repro.models import attention as A
 
 SHAPE = (4, 512, 2048)   # qwen3-1.7b: global_batch 4 x seq_len 512 x d_model
 ETA = 0.9
@@ -78,3 +81,21 @@ def test_kernel_keeps_its_name_for_v5e(one_chip, name):
     calls = re.findall(
         r'%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text)
     assert calls and all(re.fullmatch(rf"{name}(\.\d+)?", c) for c in calls)
+
+
+def test_flash_attention_grad_compiles_for_v5e(one_chip):
+    """The gradient of the training path's chunked attention at S = 4,096
+    (its live-tile loops run over traced bounds) compiles for the chip."""
+    cfg = smoke_config("qwen3-1.7b")
+    b, s, h, kv, dh = 1, 4096, 2, 1, 64
+    pos = jnp.arange(s, dtype=jnp.int32)
+
+    def loss(q, k, v):
+        out = A._sdpa_chunked_flash(cfg, q, k, v, pos, pos, 0)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((b, s, h, dh), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, dh), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile()
+    assert "while" in compiled.as_text()
