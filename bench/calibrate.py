@@ -16,7 +16,8 @@ frozen (``frozen_norms``, ``bench/faults.py``). A step that returns its
 state unchanged reads 1 on ``change_leaf`` by the comparison's own
 measure and needs no run. Each reading is one JSON line, with the worst
 own-scale gap of each kind of leaf (``kinds``), to standard output and
-to ``--out``.
+to ``--out``. The program and the reference run on the cell's chips,
+the reference being the one its configuration names.
 """
 from __future__ import annotations
 
@@ -50,8 +51,7 @@ def main(argv=None) -> int:
     bm = spec.benchmark()
     w = spec.workload(bm, a.workload)
     cfile, traffic = spec.config(bm, w["config"]), spec.traffic(w["traffic"])
-    chips = int(w["chips"])
-    require_chips(chips)
+    devices = require_chips(int(w["chips"]))
     enable_cache()
     from bench import cell, compare, faults
     out = open(a.out, "a") if a.out else None
@@ -69,24 +69,27 @@ def main(argv=None) -> int:
 
     for i, seed in enumerate(int(s) for s in a.seeds.split(",")):
         t = time.perf_counter()
-        obs = cell.drive(cfile, traffic, seed, 0.0, t_start=t, chips=chips)
+        obs = cell.drive(cfile, traffic, seed, 0.0, t_start=t,
+                         devices=devices)
         t_ref = time.perf_counter()
-        ref = cell.reference_numbers(cfile, traffic, seed, obs.batches)
+        ref = cell.reference_numbers(cfile, traffic, seed, obs.batches,
+                                     devices=devices)
         emit("program", seed, obs.prog, ref, {
             "loss_program": obs.prog["loss"], "loss_reference": ref["loss"],
             "program_s": t_ref - t,
             "reference_s": time.perf_counter() - t_ref})
         if i < a.control:
             ctrl = cell.reference_numbers(cfile, traffic, seed, obs.batches,
-                                          matmul=FP8)
+                                          matmul=FP8, devices=devices)
             emit("control", seed, ctrl, ref, {"loss_control": ctrl["loss"]})
         if i < a.half_batch:
             half = [faults.half_batch(b) for b in obs.batches]
-            hb = cell.reference_numbers(cfile, traffic, seed, half)
+            hb = cell.reference_numbers(cfile, traffic, seed, half,
+                                        devices=devices)
             emit("half_batch", seed, hb, ref, {"loss_half": hb["loss"]})
         if i < a.frozen_norms:
             fz = cell.drive(cfile, traffic, seed, 0.0,
-                            t_start=time.perf_counter(), chips=chips,
+                            t_start=time.perf_counter(), devices=devices,
                             fault=faults.FrozenNorms)
             emit("frozen_norms", seed, fz.prog, ref)
     return 0

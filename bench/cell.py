@@ -13,6 +13,12 @@ it copies the batches and the initial weights to the host before the
 first steps, reads the first clipped gradient from the optimizer's first
 moment after step 1, and each leaf's change from the f32 master weights
 after the last observed step. Its time is left out of ``setup_s``.
+
+The program runs on exactly the cell's chips: the Trainer's mesh is the
+program's host mesh over those devices. What the harness knows of the
+model's block (its published keys, its leaf names, its FLOPs, its
+reference) comes from the reference module the configuration names
+(``bench/spec.py``), carried in the run's context as ``reference``.
 """
 from __future__ import annotations
 
@@ -22,40 +28,20 @@ import gc
 import os
 import shutil
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.train import Trainer, TrainerConfig
 from repro.train.loop import TrainLoop
 
-from bench import compare, tracereduce
-from bench.reference import lm as reference
+from bench import compare, spec, tracereduce
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-# the program's parameter tree -> the reference's leaf names
-_LAYER = ("blocks", "period", "sub0")
-_LAYER_LEAF = {
-    ("mixer_norm", "scale"): "attn_norm", ("mixer", "wq"): "wq",
-    ("mixer", "wk"): "wk", ("mixer", "wv"): "wv", ("mixer", "wo"): "wo",
-    ("mixer", "q_norm", "scale"): "q_norm",
-    ("mixer", "k_norm", "scale"): "k_norm",
-    ("mlp_norm", "scale"): "mlp_norm", ("mlp", "w_gate"): "w_gate",
-    ("mlp", "w_up"): "w_up", ("mlp", "w_down"): "w_down"}
-_TOP_LEAF = {("embed",): "embed", ("head",): "head",
-             ("final_norm", "scale"): "final_norm"}
-
-# published config.json key -> the repo ModelConfig field it must equal
-_PUBLISHED = {
-    "hidden_size": "d_model", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "num_hidden_layers": "n_layers",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "param_dtype"}
 
 
 class StopWindow(Exception):
@@ -65,13 +51,11 @@ class StopWindow(Exception):
 
 def model_config(cfile: Dict):
     """The repo model of a configuration file, checked against the file's
-    published keys (the file holds the configuration as it is run)."""
+    published keys (the file holds the configuration as it is run) by the
+    fields its reference module says they fix."""
     cfg = dataclasses.replace(get_config(cfile["arch"]), **cfile["program"])
-    pub, m = cfile["published"], reference.sizes(cfile["published"])
-    want = {f: pub[k] for k, f in _PUBLISHED.items() if k in pub}
-    want.update(d_head=m.d_head, qk_norm=m.qk_norm)
+    want = spec.reference(cfile).program_fields(cfile["published"])
     got = {f: getattr(cfg, f) for f in want}
-    got["n_layers"] = cfg.n_layers
     if got != want:
         diff = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
         raise SystemExit(f"bench: {cfile['name']}: the program's model "
@@ -92,7 +76,24 @@ def trainer_config(cfile: Dict, traffic: Dict, seed: int) -> TrainerConfig:
         emit="memory", log_every=10 ** 9)
 
 
-def check_trainer(trainer: Trainer, traffic: Dict) -> None:
+def host_mesh(devices) -> jax.sharding.Mesh:
+    """The program's host mesh (``repro.launch.mesh.make_host_mesh``: data
+    over every device, Auto axes) over exactly ``devices``."""
+    return jax.make_mesh((len(devices), 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
+
+
+def build_trainer(cfile: Dict, traffic: Dict, seed: int, devices,
+                  step_hook: Optional[Callable[[int], None]] = None
+                  ) -> Trainer:
+    """The cell's Trainer, its mesh over the cell's ``devices``."""
+    trainer = Trainer(trainer_config(cfile, traffic, seed),
+                      step_hook=step_hook, model_config=model_config(cfile))
+    trainer.mesh = host_mesh(devices)
+    return trainer
+
+
+def check_trainer(trainer: Trainer, traffic: Dict, reference) -> None:
     """The Trainer runs the integrator and optimizer the reference follows."""
     opt = trainer.opt_cfg
     want = traffic["optimizer"]
@@ -108,19 +109,34 @@ def check_trainer(trainer: Trainer, traffic: Dict) -> None:
                          f"traffic file: {got} vs {want}")
 
 
-def _named(tree):
-    """(name, per_layer, leaf) of every leaf of a program parameter-shaped
-    tree, named as the reference names them (``L{i}.`` prefixed one per
-    layer for the stacked leaves)."""
-    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        k = tuple(str(getattr(p, "key", getattr(p, "name", p)))
-                  for p in path)
-        if k[:3] == _LAYER and k[3:] in _LAYER_LEAF:
-            yield _LAYER_LEAF[k[3:]], True, x
-        elif k in _TOP_LEAF:
-            yield _TOP_LEAF[k], False, x
+def _key(p) -> str:
+    return str(getattr(p, "key", getattr(p, "name", getattr(p, "idx", p))))
+
+
+def _named(tree, reference):
+    """(name, layers, leaf) of every leaf of a program parameter-shaped
+    tree, named by the configuration's reference module. ``layers`` is
+    None outside ``blocks``, the layer's number for a prelude layer's leaf,
+    and a tuple of one number per period for a period's leaf (stacked over
+    the periods): prelude layers come first, then each period's sublayers
+    in order."""
+    leaves = [(tuple(_key(p) for p in path), x) for path, x in
+              jax.tree_util.tree_flatten_with_path(tree)[0]]
+    n_pre = len({k[2] for k, _ in leaves if k[:2] == ("blocks", "prelude")})
+    n_sub = len({k[2] for k, _ in leaves if k[:2] == ("blocks", "period")})
+    for k, x in leaves:
+        if k[:2] == ("blocks", "prelude"):
+            layers = int(k[2])
+            name = reference.layer_leaf("prelude", layers, k[3:])
+        elif k[:2] == ("blocks", "period") and k[2].startswith("sub"):
+            j = int(k[2][3:])
+            layers = tuple(n_pre + p * n_sub + j for p in range(x.shape[0]))
+            name = reference.layer_leaf("period", j, k[3:])
         else:
+            layers, name = None, reference.top_leaf(k)
+        if name is None:
             raise SystemExit(f"bench: unknown parameter leaf {k}")
+        yield name, layers, x
 
 
 @functools.partial(jax.jit, static_argnames="per_layer")
@@ -129,31 +145,31 @@ def _norm(x, per_layer):
     return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(int(per_layer), x.ndim))))
 
 
-def _put(out, name, per_layer, norms, scale=1.0):
-    if per_layer:
-        for i, n in enumerate(np.ravel(norms)):
-            out[f"L{i}.{name}"] = float(n) * scale
-    else:
+def _put(out, name, layers, norms, scale=1.0):
+    if layers is None:
         out[name] = float(norms) * scale
+    else:
+        for i, n in zip(np.ravel(layers), np.ravel(norms)):
+            out[f"L{i}.{name}"] = float(n) * scale
 
 
-def leaf_norms(tree, scale: float = 1.0) -> Dict[str, float]:
+def leaf_norms(tree, reference, scale: float = 1.0) -> Dict[str, float]:
     """Norm of every leaf of a device tree shaped like the parameters."""
     out: Dict[str, float] = {}
-    for name, per_layer, x in _named(tree):
-        _put(out, name, per_layer, _norm(x, per_layer), scale)
+    for name, layers, x in _named(tree, reference):
+        _put(out, name, layers, _norm(x, isinstance(layers, tuple)), scale)
     return out
 
 
-def change_norms(tree, before) -> Dict[str, float]:
+def change_norms(tree, before, reference) -> Dict[str, float]:
     """Norm of every leaf's change from ``before`` (a host copy), worked
     out on the host so that it adds nothing to the chip's peak memory."""
     out: Dict[str, float] = {}
-    for (name, per_layer, x), b in zip(_named(tree),
-                                       jax.tree_util.tree_leaves(before)):
+    for (name, layers, x), b in zip(_named(tree, reference),
+                                    jax.tree_util.tree_leaves(before)):
         d = np.asarray(x, np.float32) - np.asarray(b, np.float32)
-        axes = tuple(range(int(per_layer), d.ndim))
-        _put(out, name, per_layer,
+        axes = tuple(range(int(isinstance(layers, tuple)), d.ndim))
+        _put(out, name, layers,
              np.sqrt(np.sum(d * d, axis=axes, dtype=np.float64)))
     return out
 
@@ -161,9 +177,9 @@ def change_norms(tree, before) -> Dict[str, float]:
 class ObservedLoop(TrainLoop):
     """The Trainer's train loop, observed during its first steps."""
 
-    def __init__(self, inner: TrainLoop, n_ref: int, b1: float):
+    def __init__(self, inner: TrainLoop, n_ref: int, b1: float, reference):
         self.inner, self.name = inner, inner.name
-        self.n_ref, self.b1 = n_ref, b1
+        self.n_ref, self.b1, self.reference = n_ref, b1, reference
         self.spans = False
         self.calls, self.capture_s = 0, 0.0
         self.batches: List[Dict] = []
@@ -192,9 +208,11 @@ class ObservedLoop(TrainLoop):
             t = time.perf_counter()
             opt = out[1]
             if i == 0:   # m after one step is (1 - b1) times the gradient
-                self.grad = leaf_norms(opt.m, scale=1.0 / (1.0 - self.b1))
+                self.grad = leaf_norms(opt.m, self.reference,
+                                       scale=1.0 / (1.0 - self.b1))
             if i == self.n_ref - 1:
-                self.change = change_norms(opt.master, self._p0)
+                self.change = change_norms(opt.master, self._p0,
+                                           self.reference)
                 self._p0 = None
             self.capture_s += time.perf_counter() - t
         return out
@@ -288,12 +306,13 @@ class Observation:
 
 def drive(cfile: Dict, traffic: Dict, seed: int, seconds: float, *,
           t_start: float, trace_dir: Optional[str] = None,
-          chips: int = 1,
+          devices: Optional[Sequence] = None,
           fault: Optional[Callable[[TrainLoop], TrainLoop]] = None
           ) -> Observation:
-    """Set-up, the window and the observation of one Trainer run."""
-    cfg = model_config(cfile)
-    tc = trainer_config(cfile, traffic, seed)
+    """Set-up, the window and the observation of one Trainer run on
+    ``devices`` (the cell's chips; the first device where not given)."""
+    devices = list(devices or jax.local_devices()[:1])
+    reference = spec.reference(cfile)
     warm, n_ref = int(traffic["warmup_steps"]), int(
         traffic["reference_steps"])
     if not 1 <= n_ref <= warm:
@@ -301,10 +320,11 @@ def drive(cfile: Dict, traffic: Dict, seed: int, seconds: float, *,
     if trace_dir and os.path.isdir(trace_dir):
         shutil.rmtree(trace_dir)
     win = Window(warm, seconds, trace_dir)
-    trainer = Trainer(tc, step_hook=win.hook, model_config=cfg)
-    check_trainer(trainer, traffic)
+    trainer = build_trainer(cfile, traffic, seed, devices, win.hook)
+    tc = trainer.config
+    check_trainer(trainer, traffic, reference)
     inner = fault(trainer.loop) if fault is not None else trainer.loop
-    loop = ObservedLoop(inner, n_ref, trainer.opt_cfg.b1)
+    loop = ObservedLoop(inner, n_ref, trainer.opt_cfg.b1, reference)
     trainer.loop = win.loop = loop
     jax.monitoring.register_event_duration_secs_listener(win.on_compile)
     gc.callbacks.append(win.on_gc)
@@ -319,7 +339,6 @@ def drive(cfile: Dict, traffic: Dict, seed: int, seconds: float, *,
         gc.callbacks.remove(win.on_gc)
     if win.setup_end is None:
         raise SystemExit("bench: the run ended before its window opened")
-    devices = jax.local_devices()[:chips]
     stats = [d.memory_stats() for d in devices]
     peak = peak_bytes(stats)
     recs = trainer.records
@@ -327,7 +346,7 @@ def drive(cfile: Dict, traffic: Dict, seed: int, seconds: float, *,
     prog = {"loss": [recs[i].loss for i in range(n_ref)],
             "grad": loop.grad, "change": loop.change}
     ctx = {
-        "chips": chips, "seed": seed,
+        "chips": len(devices), "seed": seed,
         "device_kind": devices[0].device_kind,
         "tokens_per_step": tc.global_batch * tc.seq_len,
         "seq_len": tc.seq_len, "global_batch": tc.global_batch,
@@ -341,6 +360,7 @@ def drive(cfile: Dict, traffic: Dict, seed: int, seconds: float, *,
         "step_s": [recs[i].wall_s for i in window if i in recs],
         "step_cpu_s": [float(x) for x in np.diff(win.cpu_s)],
         "gc_pauses": win.gc_pauses,
+        "reference": reference,
         "model": reference.sizes(cfile["published"]),
         "job": reference.job(traffic),
     }
@@ -353,26 +373,28 @@ def drive(cfile: Dict, traffic: Dict, seed: int, seconds: float, *,
 
 
 def reference_numbers(cfile: Dict, traffic: Dict, seed: int,
-                      batches: List[Dict], matmul: Optional[str] = None
-                      ) -> Dict:
+                      batches: List[Dict], matmul: Optional[str] = None,
+                      devices: Optional[Sequence] = None) -> Dict:
     """The reference's loss per step, first clipped gradient and change
-    per leaf, over the observed batches."""
+    per leaf, over the observed batches, spread over ``devices``."""
+    reference = spec.reference(cfile)
     return reference.run(seed, reference.sizes(cfile["published"]),
                          reference.job(traffic),
                          [(b["tokens"], b["labels"]) for b in batches],
-                         matmul)
+                         matmul, devices)
 
 
 def run(cfile: Dict, traffic: Dict, limits: Dict, seed: int,
         seconds: float, *, t_start: float, trace_dir: Optional[str] = None,
-        chips: int = 1,
+        devices: Optional[Sequence] = None,
         fault: Optional[Callable[[TrainLoop], TrainLoop]] = None) -> Dict:
-    """One run: the window, then (the program's state freed) the reference
-    and the comparison."""
+    """One run on ``devices``: the window, then (the program's state freed)
+    the reference and the comparison."""
     obs = drive(cfile, traffic, seed, seconds, t_start=t_start,
-                trace_dir=trace_dir, chips=chips, fault=fault)
+                trace_dir=trace_dir, devices=devices, fault=fault)
     t = time.perf_counter()
-    ref = reference_numbers(cfile, traffic, seed, obs.batches)
+    ref = reference_numbers(cfile, traffic, seed, obs.batches,
+                            devices=devices)
     obs.ctx["reference_s"] = time.perf_counter() - t
     correct, checks = compare.judge(compare.gaps(obs.prog, ref), limits)
     return {"correct": correct, "checks": checks, "ctx": obs.ctx,

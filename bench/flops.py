@@ -6,10 +6,9 @@ residual branch with ``P`` weights, a forward f-eval costs ``2P`` and its
 vector-Jacobian product ``4P``; an ODE branch solved by ALF with ``n``
 fixed steps makes ``n + 1`` f-evals (``v0 = f(z0)`` and one per step),
 so it requires ``6 (n + 1) P`` (18P at n = 2), a discrete branch ``6P``.
-Attention scores add, per f-eval and token, ``2 H d_head S`` forward
-(``QK^T`` and ``PV`` over the causal half of the keys), three times that
-with the backward. The head adds ``6 D V``; the embedding lookup is not
-counted.
+What a block adds beside its branches' weights (attention scores, the
+head) is its reference module's to count (``required_flops_per_token``
+there, ``bench/spec.py``).
 
 The *executed* count of an ODE branch under MALI adds what MALI
 recomputes: the backward reconstructs each step by the ALF inverse (one
@@ -26,7 +25,7 @@ elementwise over the flattened ODE state.
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Tuple
+from typing import List, Tuple
 
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
                 "s16": 2, "u16": 2, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
@@ -49,23 +48,6 @@ def branch_units(ode: bool, n_steps: int, executed: bool = False) -> float:
 def evals_per_branch(ode: bool, n_steps: int) -> int:
     """Forward f-evals of one residual branch."""
     return n_steps + 1 if ode else 1
-
-
-def branch_weights(m) -> Dict[str, int]:
-    """Matmul weights of one layer's attention and MLP branch."""
-    attn = m.d_model * m.d_head * (2 * m.n_heads + 2 * m.n_kv_heads)
-    mlp = 3 * m.d_model * m.d_ff
-    return {"attn": attn, "mlp": mlp}
-
-
-def required_flops_per_token(m, job, seq_len: int) -> float:
-    """FLOPs per token that one training step requires (see module doc)."""
-    w = branch_weights(m)
-    units = branch_units(job.ode, job.n_steps)
-    evals = evals_per_branch(job.ode, job.n_steps)
-    scores = 3 * 2 * m.n_heads * m.d_head * seq_len * evals
-    per_layer = units * (w["attn"] + w["mlp"]) + scores
-    return m.n_layers * per_layer + 6.0 * m.d_model * m.vocab_size
 
 
 def hlo_shapes(instruction: str) -> List[Tuple[str, Tuple[int, ...], int]]:

@@ -22,15 +22,28 @@ configuration states.
 ``matmul="float8_e4m3fn"`` rounds both operands of every matrix product to
 float8 with a per-tensor scale: the lower-precision control that the
 comparison has to reject.
+
+Given a cell's chips, the step spreads the layers over them
+(``bench/reference/placement.py``): the same ops in the same order, each
+layer's weights, gradients and input on its own chip.
+
+This is the reference module of every configuration that names none: it
+also holds what the harness needs to know of this block (``bench/spec.py``
+states the interface): the program fields the published keys fix, the
+names of the program's parameter leaves, and the FLOPs a step requires.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.flops import branch_units, evals_per_branch
+from bench.reference.placement import Placement, put
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NORM_EPS = 1e-6
@@ -99,6 +112,74 @@ def job(traffic: Dict) -> Job:
 
 
 # --------------------------------------------------------------------------
+# the program's model as this block sees it
+# --------------------------------------------------------------------------
+
+# published config.json key -> the repo ModelConfig field it must equal
+_PUBLISHED = {
+    "hidden_size": "d_model", "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size", "num_hidden_layers": "n_layers",
+    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
+    "torch_dtype": "param_dtype"}
+
+# a layer's parameter path in the program -> the reference's leaf name
+_LAYER_LEAF = {
+    ("mixer_norm", "scale"): "attn_norm", ("mixer", "wq"): "wq",
+    ("mixer", "wk"): "wk", ("mixer", "wv"): "wv", ("mixer", "wo"): "wo",
+    ("mixer", "q_norm", "scale"): "q_norm",
+    ("mixer", "k_norm", "scale"): "k_norm",
+    ("mlp_norm", "scale"): "mlp_norm", ("mlp", "w_gate"): "w_gate",
+    ("mlp", "w_up"): "w_up", ("mlp", "w_down"): "w_down"}
+_TOP_LEAF = {("embed",): "embed", ("head",): "head",
+             ("final_norm", "scale"): "final_norm"}
+
+
+def program_fields(published: Dict) -> Dict:
+    """The fields of the program's ``ModelConfig`` that a configuration's
+    published keys fix, with the values they must have."""
+    m = sizes(published)
+    want = {f: published[k] for k, f in _PUBLISHED.items()
+            if k in published}
+    want.update(d_head=m.d_head, qk_norm=m.qk_norm, n_layers=m.n_layers)
+    return want
+
+
+def layer_leaf(group: str, index: int, path: Tuple[str, ...]
+               ) -> Optional[str]:
+    """The reference's name of the program's leaf ``path`` inside layer
+    ``index`` of ``group``: one decoder layer, the period's ``sub0``."""
+    return _LAYER_LEAF.get(path) if (group, index) == ("period", 0) else None
+
+
+def top_leaf(path: Tuple[str, ...]) -> Optional[str]:
+    """The reference's name of a program leaf outside the layers."""
+    return _TOP_LEAF.get(path)
+
+
+def branch_weights(m) -> Dict[str, int]:
+    """Matmul weights of one layer's attention and MLP branch."""
+    attn = m.d_model * m.d_head * (2 * m.n_heads + 2 * m.n_kv_heads)
+    mlp = 3 * m.d_model * m.d_ff
+    return {"attn": attn, "mlp": mlp}
+
+
+def required_flops_per_token(m, job, seq_len: int) -> float:
+    """FLOPs per token that one training step requires: each layer's
+    attention and MLP branch as ``bench/flops.py`` counts a branch;
+    attention scores add, per f-eval and token, ``2 H d_head S`` forward
+    (``QK^T`` and ``PV`` over the causal half of the keys), three times
+    that with the backward; the head adds ``6 D V``; the embedding lookup
+    is not counted."""
+    w = branch_weights(m)
+    units = branch_units(job.ode, job.n_steps)
+    evals = evals_per_branch(job.ode, job.n_steps)
+    scores = 3 * 2 * m.n_heads * m.d_head * seq_len * evals
+    per_layer = units * (w["attn"] + w["mlp"]) + scores
+    return m.n_layers * per_layer + 6.0 * m.d_model * m.vocab_size
+
+
+# --------------------------------------------------------------------------
 # initialisation (seeded; same draws as the model's documented init)
 # --------------------------------------------------------------------------
 
@@ -136,7 +217,8 @@ def _keys(seed: int, n_layers: int):
     return ke, kh, layer_keys
 
 
-def _init_layer(key, m):
+def init_layer(key, m):
+    """One layer's weights from its key, in float32."""
     d, h, kv, dh, ff = (m.d_model, m.n_heads, m.n_kv_heads, m.d_head, m.d_ff)
     k1, k2 = jax.random.split(jax.random.split(key, 1)[0])
     kq, kk, kvv, ko = jax.random.split(k1, 4)
@@ -161,17 +243,31 @@ def _init_embed(key, shape, dtype):
     return _round(_embed_draw(key, shape), dtype)
 
 
-def init_params(seed: int, m) -> Dict:
-    """The model's initial weights, in float32, from ``seed``."""
+def _on(device):
+    """Arrays made in this context without inputs go to ``device``."""
+    return (contextlib.nullcontext() if device is None
+            else jax.default_device(device))
+
+
+def _init_on(device, init, key, *args):
+    with _on(device):
+        return init(put(key, device), *args)
+
+
+def init_params(seed: int, m, place: Optional[Placement] = None) -> Dict:
+    """The model's initial weights, in float32, from ``seed``; each part on
+    its chip where ``place`` spreads them."""
+    place = place or Placement(None, m.n_layers)
     ke, kh, layer_keys = _keys(seed, m.n_layers)
-    params = {"embed": _init_embed(ke, (m.vocab_size, m.d_model),
-                                   m.param_dtype),
-              "final_norm": jnp.ones((m.d_model,), jnp.float32),
-              "layers": [_init_layer(layer_keys[i], m)
-                         for i in range(m.n_layers)]}
+    params = {"embed": _init_on(place.first, _init_embed, ke,
+                                (m.vocab_size, m.d_model), m.param_dtype)}
+    with _on(place.last):
+        params["final_norm"] = jnp.ones((m.d_model,), jnp.float32)
+    params["layers"] = [_init_on(place.layer(i), init_layer, layer_keys[i], m)
+                        for i in range(m.n_layers)]
     if not m.tie_embeddings:
-        params["head"] = _init_embed(kh, (m.d_model, m.vocab_size),
-                                     m.param_dtype)
+        params["head"] = _init_on(place.last, _init_embed, kh,
+                                  (m.d_model, m.vocab_size), m.param_dtype)
     return params
 
 
@@ -283,7 +379,7 @@ def _layer_fwd(lp, x, m, job, mm):
 
 @functools.partial(jax.jit, static_argnames=("m", "job", "mm"),
                    donate_argnums=(0,))
-def _layer_vjp(g_acc, lp, x, gy, m, job, mm):
+def layer_vjp(g_acc, lp, x, gy, m, job, mm):
     """Adds this row's parameter cotangent to ``g_acc``; returns it and the
     input cotangent."""
     with jax.default_matmul_precision("highest"):
@@ -294,7 +390,7 @@ def _layer_vjp(g_acc, lp, x, gy, m, job, mm):
 
 @functools.partial(jax.jit, static_argnames=("mm", "wdt"),
                    donate_argnums=(0,))
-def _head_vjp(g_head_acc, fn_scale, head, x, labels, scale, mm, wdt):
+def head_vjp(g_head_acc, fn_scale, head, x, labels, scale, mm, wdt):
     """Chunked next-token CE of one row: (nll sum, d final_norm, d head
     accumulated, d x). Cotangents carry the mean's ``scale``."""
     s, d = x.shape
@@ -360,36 +456,46 @@ def _flat(params: Dict) -> List:
 # --------------------------------------------------------------------------
 
 def loss_and_grads(params: Dict, tokens: np.ndarray, labels: np.ndarray,
-                   m, job, mm: Optional[str] = None):
+                   m, job, mm: Optional[str] = None,
+                   place: Optional[Placement] = None):
     """Mean next-token CE over every token of the batch and its gradient,
-    one row at a time and one layer at a time."""
+    one row at a time and one layer at a time; each layer's input and
+    gradient on its chip where ``place`` spreads the layers."""
+    place = place or Placement(None, m.n_layers)
     b, s = tokens.shape
     scale = jnp.float32(1.0 / (b * s))
-    head = params["embed"].T if m.tie_embeddings else params["head"]
+    head = (put(params["embed"].T, place.last) if m.tie_embeddings
+            else params["head"])
     layers = params["layers"]
     nll_total = 0.0
-    g_head = jnp.zeros(head.shape, jnp.float32)
-    g_fn = jnp.zeros_like(params["final_norm"])
-    g_layers = [jax.tree_util.tree_map(jnp.zeros_like, lp) for lp in layers]
-    g_embed = jnp.zeros(params["embed"].shape, jnp.float32)
+    with _on(place.last):
+        g_head = jnp.zeros(head.shape, jnp.float32)
+        g_fn = jnp.zeros_like(params["final_norm"])
+    g_layers = []
+    for i, lp in enumerate(layers):
+        with _on(place.layer(i)):
+            g_layers.append(jax.tree_util.tree_map(jnp.zeros_like, lp))
+    with _on(place.first):
+        g_embed = jnp.zeros(params["embed"].shape, jnp.float32)
     for r in range(b):
         tok = jnp.asarray(tokens[r])
         xs = [_round(params["embed"][tok], m.param_dtype)]
-        for lp in layers:
-            xs.append(_layer_fwd(lp, xs[-1], m, job, mm))
-        nll, gf, g_head, gy = _head_vjp(
-            g_head, params["final_norm"], head, xs[-1],
+        for i, lp in enumerate(layers):
+            xs[i] = put(xs[i], place.layer(i))
+            xs.append(_layer_fwd(lp, xs[i], m, job, mm))
+        nll, gf, g_head, gy = head_vjp(
+            g_head, params["final_norm"], head, put(xs[-1], place.last),
             jnp.asarray(labels[r]), scale, mm, m.param_dtype)
         nll_total += float(nll)
         g_fn = g_fn + gf
         for i in range(len(layers) - 1, -1, -1):
-            g_layers[i], gy = _layer_vjp(g_layers[i], layers[i], xs[i], gy,
-                                         m, job, mm)
+            g_layers[i], gy = layer_vjp(g_layers[i], layers[i], xs[i],
+                                        put(gy, place.layer(i)), m, job, mm)
         del xs
-        g_embed = _embed_vjp(g_embed, tok, gy)
+        g_embed = _embed_vjp(g_embed, tok, put(gy, place.first))
     grads = {"embed": g_embed, "final_norm": g_fn, "layers": g_layers}
     if m.tie_embeddings:
-        grads["embed"] = grads["embed"] + g_head.T
+        grads["embed"] = grads["embed"] + put(g_head.T, place.first)
     else:
         grads["head"] = g_head
     return nll_total / (b * s), grads
@@ -430,19 +536,22 @@ def global_norm(grads: Dict) -> float:
     return float(np.sqrt(sum(float(_sq(a)) for a in _flat(grads))))
 
 
-def run(seed: int, m, job, batches: Sequence, mm: Optional[str] = None
-        ) -> Dict:
-    """The first ``len(batches)`` training steps from the seeded weights.
+def run(seed: int, m, job, batches: Sequence, mm: Optional[str] = None,
+        devices: Optional[Sequence] = None) -> Dict:
+    """The first ``len(batches)`` training steps from the seeded weights,
+    spread over ``devices`` (the cell's chips) where there are several.
 
     Returns each step's loss, the norm of every leaf of the first clipped
     gradient, and the norm of every leaf's change after the last step.
     """
     opt = job.optimizer
-    params = init_params(seed, m)
+    place = Placement(devices, m.n_layers)
+    params = init_params(seed, m, place)
     history, scales, losses = [], [], []
     first = None
     for t, (tokens, labels) in enumerate(batches, start=1):
-        loss, grads = loss_and_grads(params, tokens, labels, m, job, mm)
+        loss, grads = loss_and_grads(params, tokens, labels, m, job, mm,
+                                     place)
         losses.append(loss)
         gnorm = global_norm(grads)
         sc = min(1.0, opt.clip_norm / max(gnorm, 1e-12))
@@ -460,7 +569,7 @@ def run(seed: int, m, job, batches: Sequence, mm: Optional[str] = None
         params = _unflat(params, new)
         del flat, new
     del history
-    init = init_params(seed, m)
+    init = init_params(seed, m, place)
     change = leaf_norms(_tree_sub(params, init))
     return {"loss": losses, "grad": first, "change": change}
 

@@ -8,7 +8,8 @@ model through ``repro.train.Trainer.train``: set-up (weights from the
 seed, the first steps, which compile), then the measured window of at
 least ``--seconds``, then, with the program's state freed, the plain
 float32 reference over the first steps and the comparison that decides
-``correct``. The last line of standard output is one JSON object:
+``correct``. The program and the reference run on the cell's chips and
+no others. The last line of standard output is one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
 metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``),
 ``device``, with ``--trace 1`` a ``breakdown`` of the traced window, and
@@ -111,8 +112,7 @@ def main(argv=None) -> int:
     trace_dir = (os.path.join(_HERE, "out", a.workload, "trace")
                  if a.trace else None)
     res = cell.run(cfile, traffic, limits, a.seed, a.seconds,
-                   t_start=T_START, trace_dir=trace_dir,
-                   chips=int(w["chips"]))
+                   t_start=T_START, trace_dir=trace_dir, devices=devices)
     line = result_line(res, bm, a.workload, bool(a.trace), devices)
     ctx = res["ctx"]
     print(f"window: {ctx['window_steps']} steps in {ctx['window_s']!r} s; "

@@ -5,18 +5,52 @@ and traffic; the harness reads
 
 * ``bench/configs/<config>.json``: the model as it is run, in the keys of
   its published ``config.json``, plus the repo architecture it is built
-  from (``arch``) and the fields of that architecture it replaces
-  (``program``);
+  from (``arch``), the fields of that architecture it replaces
+  (``program``) and, optionally, the reference module of its block
+  (``reference``, a module of ``bench/reference/``; ``lm`` without it);
 * ``bench/traffic/<traffic>.json``: the training job: batch, sequence
   length, integrator and optimizer settings, warm-up steps;
 * ``bench/limits/<cell>.json``: the limit of each compared number;
 * ``bench/metrics/<metric>.py``: one reader per metric, ``read(ctx)``
   returning a number, or None where it finds nothing to read.
 
-Adding a cell or a metric adds files; no file here changes.
+A reference module (``bench/reference/lm.py`` is one) holds everything
+the harness knows of one architecture; nothing of it imports the
+program. It has these functions (``REFERENCE_API``):
+
+* ``sizes(published)``: the model's sizes, hashable, from the published
+  keys (``ctx["model"]``);
+* ``job(traffic)``: integrator and optimizer settings, hashable
+  (``ctx["job"]``);
+* ``program_fields(published)``: ``{ModelConfig field: value}`` that the
+  program's model must have;
+* ``layer_leaf(group, index, path)``: the reference's name of the
+  program's parameter leaf at ``blocks/<group>/...``: ``group``
+  ``"prelude"`` with ``index`` the prelude layer, or ``"period"`` with
+  ``index`` the ``k`` of ``sub<k>``, and ``path`` the keys inside that
+  layer; None where the block has no such leaf, which stops the run.
+  The harness numbers the layers (prelude layers first, then each
+  period's sublayers in order) and names a layer's leaf ``L<i>.<name>``;
+* ``top_leaf(path)``: the same for a leaf outside ``blocks``;
+* ``required_flops_per_token(sizes, job, seq_len)``: what a training
+  step requires, for ``train_step_mfu``;
+* ``run(seed, sizes, job, batches, matmul, devices)``: the first steps
+  from its own weights over the observed ``(tokens, labels)`` batches, in
+  float32, spread over the cell's ``devices`` where there are several
+  (``bench/reference/placement.py``): ``{"loss": [...], "grad": {leaf:
+  norm}, "change": {leaf: norm}}``;
+* ``init_params(seed, sizes)``: its weights as ``{"embed", ..., "layers":
+  [one dict per layer]}``; ``init_layer(key, sizes)``, ``layer_vjp``
+  and ``head_vjp``: one layer's weights and the two jitted blocks whose
+  memory ``bench/aot_fit.py`` reckons.
+
+Adding a cell or a metric adds files; so does adding a configuration of a
+new architecture: the configuration, its reference module where none
+fits, its limits. No file here changes.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -49,6 +83,23 @@ def config(bm: Dict, name: str) -> Dict:
         if c["name"] == name:
             return _json(os.path.join(ROOT, c["file"]))
     raise SystemExit(f"bench: no configuration {name!r} in BENCHMARK.json")
+
+
+REFERENCE_API = ("sizes", "job", "program_fields", "layer_leaf", "top_leaf",
+                 "required_flops_per_token", "run", "init_params",
+                 "init_layer", "layer_vjp", "head_vjp")
+
+
+def reference(cfile: Dict):
+    """The reference module a configuration file names (``lm`` where it
+    names none), checked for every function of ``REFERENCE_API``."""
+    name = cfile.get("reference", "lm")
+    mod = importlib.import_module(f"bench.reference.{name}")
+    missing = [f for f in REFERENCE_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"bench: reference module {name!r} lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 def traffic(name: str) -> Dict:

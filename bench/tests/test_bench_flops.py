@@ -33,7 +33,7 @@ def test_required_flops_per_token_by_hand(ode):
     scores = evals * 3 * (2 * 2 * 4 * (s / 2) * 2)  # QK^T + PV, causal half
     head = 6 * 8 * 32
     want = 3 * (units * (attn + mlp) + scores) + head
-    assert flops.required_flops_per_token(m, _job(ode), s) == want
+    assert reference.required_flops_per_token(m, _job(ode), s) == want
 
 
 # ALF kernel calls as a v5e trace names them (shapes and layouts of the

@@ -26,7 +26,8 @@ def _ctx(trace=True):
     ctx = {"chips": 1, "device_kind": "TPU v5 lite", "seq_len": 4096,
            "global_batch": 2, "tokens_per_step": 8192, "window_steps": 2,
            "window_s": 3.0, "setup_s": 40.0, "peak_bytes": 12.5e9,
-           "fevals": [48, 48], "model": m, "job": job}
+           "fevals": [48, 48], "reference": reference, "model": m,
+           "job": job}
     if trace:
         ctx["trace"] = {"window_s": 3.0, "busy_s": 2.7, "ops": ops}
     return ctx, state

@@ -60,7 +60,7 @@ def test_init_draws_the_models_weights(arch, dtype):
     seed = 2 ** 33 + 7
     prog = init_lm(jax.random.PRNGKey(seed), cfg)
     ref = reference.init_params(seed, reference.sizes(cfile["published"]))
-    want = cell.leaf_norms(prog)
+    want = cell.leaf_norms(prog, reference)
     got = reference.leaf_norms(ref)
     assert set(got) == set(want)
     assert np.array_equal(np.asarray(prog["embed"], np.float32),
@@ -91,7 +91,7 @@ def test_step0_loss_and_grads_match_the_program(arch, ode):
         reference.init_params(seed, m), batch["tokens"], batch["labels"],
         m, job)
     assert abs(float(loss) - r_loss) <= 1e-5 * abs(r_loss)
-    g_prog = cell.leaf_norms(grads)
+    g_prog = cell.leaf_norms(grads, reference)
     g_ref = reference.leaf_norms(r_grads)
     gap, at = compare.leaf_gap(g_prog, g_ref, sorted(g_ref))
     assert gap <= 1e-4, (gap, at)
@@ -111,7 +111,7 @@ def _tree_diff(prog, ref):
     sub = prog["blocks"]["period"]["sub0"]
     for i, lr in enumerate(ref["layers"]):
         d = {}
-        for (k, name) in cell._LAYER_LEAF.items():
+        for (k, name) in reference._LAYER_LEAF.items():
             if name in lr:
                 leaf = sub[k[0]]
                 for part in k[1:]:
