@@ -9,6 +9,7 @@ from repro.core.ode_block import OdeSettings
 from .base import (SHAPE_CELLS, LayerSpec, ModelConfig, ShapeCell,
                    cell_applicable, get_shape_cell, uniform_pattern)
 from .deepseek_moe_16b import CONFIG as _deepseek
+from .deepseek_v2_lite import CONFIG as _deepseek_v2
 from .gemma2_2b import CONFIG as _gemma2
 from .granite_20b import CONFIG as _granite
 from .grok_1_314b import CONFIG as _grok
@@ -22,7 +23,7 @@ from .xlstm_125m import CONFIG as _xlstm
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c.validate() for c in (
         _musicgen, _internvl2, _stablelm, _qwen3, _granite, _gemma2,
-        _xlstm, _deepseek, _grok, _jamba)
+        _xlstm, _deepseek, _deepseek_v2, _grok, _jamba)
 }
 
 # The paper's own setting: continuous-depth ("Neural-ODE-18"-style) variants
@@ -56,12 +57,18 @@ def smoke_config(name: str, ode: Optional[OdeSettings] = None) -> ModelConfig:
         moe_experts=(4 if cfg.moe_experts else 0),
         moe_top_k=(2 if cfg.moe_top_k else 0),
         moe_d_ff=(32 if cfg.moe_d_ff else 0),
+        moe_experts_held=0, moe_first_expert=0,
         # dropless in smoke (cap >= N both train and serve) for exact
         # train-vs-serve consistency tests
         moe_capacity_factor=2.0, moe_eval_capacity_factor=2.0,
         prelude_d_ff=(64 if cfg.prelude_d_ff else 0),
         n_periods=min(cfg.n_periods, 2),
         mamba_d_state=8,
+        mla_kv_rank=(32 if cfg.mla_kv_rank else 0),
+        mla_nope_dim=(16 if cfg.mla_nope_dim else 0),
+        mla_rope_dim=(8 if cfg.mla_rope_dim else 0),
+        mla_v_dim=(16 if cfg.mla_v_dim else 0),
+        rope_yarn_original_len=(16 if cfg.rope_yarn_factor else 0),
         sliding_window=(8 if cfg.sliding_window else 0),
         param_dtype="float32", compute_dtype="float32",
         sharding="tp",

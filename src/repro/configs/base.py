@@ -19,7 +19,7 @@ from repro.core.ode_block import OdeSettings
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One sub-layer of a period."""
-    mixer: str = "attn"           # 'attn' | 'mamba' | 'mlstm' | 'slstm'
+    mixer: str = "attn"           # 'attn' | 'mla' | 'mamba' | 'mlstm' | 'slstm'
     mlp: str = "dense"            # 'dense' | 'moe' | 'none'
     attn_kind: str = "global"     # 'global' | 'local'  (gemma2 alternation)
 
@@ -42,8 +42,17 @@ class ModelConfig:
     moe_top_k: int = 0
     moe_shared_experts: int = 0
     moe_d_ff: int = 0              # per-(routed)-expert hidden dim
+    # device budget: rows of held-expert assignments a MoE layer computes,
+    # as a multiple of its share N * top_k * held / experts (training, and
+    # serving with eval); over it the lowest-affinity assignments drop
     moe_capacity_factor: float = 1.25
     moe_eval_capacity_factor: float = 2.0
+    moe_norm_topk: bool = True     # renormalise the top-k gate values
+    # the expert share of one chip of an expert-parallel group: experts
+    # [moe_first_expert, moe_first_expert + moe_experts_held) of the
+    # router's moe_experts; 0 holds all of them
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
     # dense-FFN override for prelude layers (DeepSeek layer-0 dense)
     prelude_d_ff: int = 0
     # attention details
@@ -52,6 +61,22 @@ class ModelConfig:
     final_softcap: float = 0.0     # gemma2: 30.0
     sliding_window: int = 0        # local-attn window (gemma2: 4096)
     rope_theta: float = 10000.0
+    # YaRN rotary (factor 0: plain RoPE): frequencies blended between
+    # interpolated and extrapolated over the correction range that
+    # beta_fast/beta_slow give at the original length
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_len: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+    # multi-head latent attention (mixer 'mla'; no query latent): K/V from
+    # a kv_rank-wide latent, q/k heads nope_dim + rope_dim wide (the rotary
+    # key shared by all heads), value heads v_dim wide
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
     # ssm (mamba) details
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
@@ -77,6 +102,10 @@ class ModelConfig:
     subquadratic: bool = False
 
     @property
+    def n_experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_experts
+
+    @property
     def n_layers(self) -> int:
         return len(self.prelude) + len(self.period) * self.n_periods
 
@@ -89,9 +118,17 @@ class ModelConfig:
     def validate(self) -> "ModelConfig":
         if self.period and self.n_periods <= 0:
             raise ValueError("n_periods must be positive when period non-empty")
-        has_moe = any(l.mlp == "moe" for l in self.prelude + self.period)
+        specs = self.prelude + self.period
+        has_moe = any(l.mlp == "moe" for l in specs)
         if has_moe and (self.moe_experts <= 0 or self.moe_top_k <= 0):
             raise ValueError(f"{self.name}: moe layers need moe_experts/top_k")
+        if has_moe and not (0 <= self.moe_first_expert and self.moe_first_expert
+                            + self.n_experts_held <= self.moe_experts):
+            raise ValueError(f"{self.name}: held experts outside the router's")
+        if any(l.mixer == "mla" for l in specs) and min(
+                self.mla_kv_rank, self.mla_nope_dim, self.mla_rope_dim,
+                self.mla_v_dim) <= 0:
+            raise ValueError(f"{self.name}: mla layers need mla_* sizes")
         self.ode.validate()
         return self
 

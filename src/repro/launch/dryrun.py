@@ -149,7 +149,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
             rep = replicated(mesh)
             metrics_sh = {"lr": rep, "grad_norm": rep, "loss": rep,
                           "ode_accepted": rep, "ode_rejected": rep,
-                          "ode_fevals": rep}
+                          "ode_fevals": rep, "moe_routed": rep,
+                          "moe_dropped": rep}
             jitted = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                              out_shardings=(p_sh, o_sh, metrics_sh),
                              donate_argnums=(0, 1))

@@ -56,6 +56,8 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
         metrics["ode_accepted"] = stats.n_accepted
         metrics["ode_rejected"] = stats.n_rejected
         metrics["ode_fevals"] = stats.n_fevals
+        metrics["moe_routed"] = stats.moe_routed
+        metrics["moe_dropped"] = stats.moe_dropped
         return params, opt_state, metrics
 
     if compress:

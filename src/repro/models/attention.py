@@ -88,34 +88,38 @@ def _mask_bias(q_pos: jax.Array, k_pos: jax.Array, window: int) -> jax.Array:
     return jnp.where(keep, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def _sdpa_direct(cfg: ModelConfig, q, k, v, bias) -> jax.Array:
-    """[B,Sq,H,dh] x [B,Sk,K,dh] grouped attention, f32 accumulation."""
+def _sdpa_direct(cfg: ModelConfig, q, k, v, bias, scale=None) -> jax.Array:
+    """[B,Sq,H,dh] x [B,Sk,K,dh] grouped attention, f32 accumulation; v may
+    be narrower than q/k ([B,Sk,K,dv]). ``scale`` defaults to dh^-1/2."""
     b, sq, h, dh = q.shape
     k_heads = k.shape[2]
     g = h // k_heads
     qg = q.reshape(b, sq, k_heads, g, dh)
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     scores = softcap(scores, cfg.attn_softcap)
     scores = scores + bias[None, None, None, :, :]
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, h, dh).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def _sdpa_chunked(cfg: ModelConfig, q, k, v, q_pos, k_pos, window,
-                  block_q: int = _BLOCK_Q, block_kv: int = _BLOCK_KV):
+                  block_q: int = _BLOCK_Q, block_kv: int = _BLOCK_KV,
+                  scale=None):
     """Flash-style online-softmax over (Q-block x KV-block) tiles in pure jnp.
 
     Memory is O(block_q * block_kv) per tile instead of O(Sq * Sk); this is
-    the backend-portable twin of the Pallas kernel.
+    the backend-portable twin of the Pallas kernel. v may be narrower than
+    q/k; ``scale`` defaults to dh^-1/2.
     """
     b, sq, h, dh = q.shape
+    dv = v.shape[-1]
     (qp, kp_x, vp_x, qpos, kpos, nq, nkv, pad_q, pad_kv, g) = _chunk_arrays(
         cfg, q, k, v, q_pos, k_pos, block_q, block_kv, ctx_parallel=True)
     k_heads = h  # _chunk_arrays repeats KV to full head count (g == 1)
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
 
     # Both loops consume their tiles as scan xs (dynamic-sliced per
     # iteration) rather than closures, so the loop state never carries the
@@ -144,15 +148,15 @@ def _sdpa_chunked(cfg: ModelConfig, q, k, v, q_pos, k_pos, window,
 
         m0 = jnp.full((b, k_heads, g, block_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, k_heads, g, block_q), jnp.float32)
-        a0 = jnp.zeros((b, k_heads, g, block_q, dh), jnp.float32)
+        a0 = jnp.zeros((b, k_heads, g, block_q, dv), jnp.float32)
         (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), (kp_x, vp_x, kpos))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return carry, out  # [B, K, G, bq, dh]
+        return carry, out  # [B, K, G, bq, dv]
 
-    _, outs = lax.scan(q_block, 0, (qp, qpos))    # [nq, B, K, G, bq, dh]
-    out = jnp.moveaxis(outs, 0, 3)                # [B, K, G, nq, bq, dh]
-    out = out.reshape(b, k_heads, g, nq * block_q, dh)[:, :, :, :sq]
-    out = jnp.moveaxis(out.reshape(b, h, sq, dh), 1, 2)
+    _, outs = lax.scan(q_block, 0, (qp, qpos))    # [nq, B, K, G, bq, dv]
+    out = jnp.moveaxis(outs, 0, 3)                # [B, K, G, nq, bq, dv]
+    out = out.reshape(b, k_heads, g, nq * block_q, dv)[:, :, :, :sq]
+    out = jnp.moveaxis(out.reshape(b, h, sq, dv), 1, 2)
     return out.astype(q.dtype)
 
 
@@ -186,7 +190,8 @@ def _chunk_arrays(cfg, q, k, v, q_pos, k_pos, block_q, block_kv,
     kpos = jnp.pad(k_pos, (0, pad_kv), constant_values=2 ** 30)
     qp = jnp.moveaxis(qp.reshape(b, nq, block_q, k_heads, g, dh), 1, 0)
     kp = jnp.moveaxis(kp.reshape(b, nkv, block_kv, k_heads, dh), 1, 0)
-    vp = jnp.moveaxis(vp.reshape(b, nkv, block_kv, k_heads, dh), 1, 0)
+    vp = jnp.moveaxis(vp.reshape(b, nkv, block_kv, k_heads, v.shape[-1]),
+                      1, 0)
     # tile stacks: batch on dp, scan axes replicated, heads on model when
     # they divide it; otherwise shard the per-tile q ROWS over 'model'
     # (context-parallel fallback for few-head archs like gemma2's 8 heads
@@ -245,8 +250,9 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
     (q,k,v,lse), accumulate dq and add dk/dv into kv-block j of the carried
     dk/dv in place — standard FA2, incl. the softcap chain rule.
 
-    Tiled layouts: q [nq, B, bq, K, G, dh]; k/v [nkv, B, bk, K, dh];
-    qpos [nq, bq]; kpos [nkv, bk]. Returns out [nq, B, K, G, bq, dh].
+    Tiled layouts: q [nq, B, bq, K, G, dh]; k [nkv, B, bk, K, dh]; v [nkv,
+    B, bk, K, dv]; qpos [nq, bq]; kpos [nkv, bk]. Returns out [nq, B, K, G,
+    bq, dv].
     """
 
     def _bias(qpb, kposb):
@@ -279,10 +285,10 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
                     "bkgqs,bskd->bkgqd", p, _tile(vp, j).astype(jnp.float32))
                 return m_new, l_new, acc_new
 
-            b, bq, kh, g, dh = qb.shape
+            b, bq, kh, g, _ = qb.shape
             m0 = jnp.full((b, kh, g, bq), NEG_INF, jnp.float32)
             l0 = jnp.zeros((b, kh, g, bq), jnp.float32)
-            a0 = jnp.zeros((b, kh, g, bq, dh), jnp.float32)
+            a0 = jnp.zeros((b, kh, g, bq, vp.shape[-1]), jnp.float32)
             m, l, acc = lax.fori_loop(lo_i, hi_i + 1, kv_step, (m0, l0, a0))
             out = acc / jnp.maximum(l, 1e-30)[..., None]
             # +inf lse for fully-masked (padding) rows => p == 0 in bwd
@@ -291,7 +297,7 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
             return carry, (out, lse)
 
         _, (outs, lses) = lax.scan(q_block, 0, (qp, qpos, lo, hi))
-        return outs, lses  # [nq,B,K,G,bq,dh], [nq,B,K,G,bq]
+        return outs, lses  # [nq,B,K,G,bq,dv], [nq,B,K,G,bq]
 
     @jax.custom_vjp
     def flash(qp, kp, vp, qpos, kpos):
@@ -354,18 +360,22 @@ def _make_flash_sdpa(softcap_val: float, window: int, scale: float,
 
 
 def _sdpa_chunked_flash(cfg: ModelConfig, q, k, v, q_pos, k_pos, window,
-                        block_q: int = _BLOCK_Q, block_kv: int = _BLOCK_KV):
-    """Differentiable chunked attention with the FA2-style backward."""
+                        block_q: int = _BLOCK_Q, block_kv: int = _BLOCK_KV,
+                        scale=None):
+    """Differentiable chunked attention with the FA2-style backward; v may
+    be narrower than q/k; ``scale`` defaults to dh^-1/2."""
     b, sq, h, dh = q.shape
+    dv = v.shape[-1]
     (qp, kp, vp, qpos, kpos, nq, nkv, pad_q, pad_kv, g) = _chunk_arrays(
         cfg, q, k, v, q_pos, k_pos, block_q, block_kv)
     k_heads = h  # _chunk_arrays repeats KV to full head count (g == 1)
     flash = _make_flash_sdpa(float(cfg.attn_softcap), int(window),
-                             float(dh ** -0.5), block_q, block_kv)
-    outs = flash(qp, kp, vp, qpos, kpos)      # [nq, B, K, G, bq, dh]
-    out = jnp.moveaxis(outs, 0, 3)            # [B, K, G, nq, bq, dh]
-    out = out.reshape(b, k_heads, g, nq * block_q, dh)[:, :, :, :sq]
-    out = jnp.moveaxis(out.reshape(b, h, sq, dh), 1, 2)
+                             float(dh ** -0.5 if scale is None else scale),
+                             block_q, block_kv)
+    outs = flash(qp, kp, vp, qpos, kpos)      # [nq, B, K, G, bq, dv]
+    out = jnp.moveaxis(outs, 0, 3)            # [B, K, G, nq, bq, dv]
+    out = out.reshape(b, k_heads, g, nq * block_q, dv)[:, :, :, :sq]
+    out = jnp.moveaxis(out.reshape(b, h, sq, dv), 1, 2)
     return out.astype(q.dtype)
 
 
@@ -383,15 +393,24 @@ def attention_train(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
         positions = jnp.tile(jnp.arange(s, dtype=jnp.int32)[None], (b, 1))
     window = cfg.sliding_window if spec.attn_kind == "local" else 0
     q, k, v = _project_qkv(params, cfg, x, positions)
+    return _finish(params, b, s, sdpa_train(cfg, q, k, v, positions, window))
+
+
+def sdpa_train(cfg: ModelConfig, q, k, v, positions: jax.Array, window: int,
+               scale=None) -> jax.Array:
+    """Causal (windowed) attention of the train path: the direct einsum up
+    to ``_DIRECT_SEQ_LIMIT``, the chunked flash ``custom_vjp`` (or, with
+    ``attn_bwd='autodiff'``, AD through the chunked scan) above it. v may
+    be narrower than q/k; ``scale`` defaults to dh^-1/2."""
+    s = q.shape[1]
     if s <= _DIRECT_SEQ_LIMIT:
         bias = _mask_bias(positions[0], positions[0], window)
-        out = _sdpa_direct(cfg, q, k, v, bias)
-    elif getattr(cfg, "attn_bwd", "flash") == "flash":
-        out = _sdpa_chunked_flash(cfg, q, k, v, positions[0], positions[0],
-                                  window)
-    else:
-        out = _sdpa_chunked(cfg, q, k, v, positions[0], positions[0], window)
-    return _finish(params, b, s, out)
+        return _sdpa_direct(cfg, q, k, v, bias, scale)
+    if getattr(cfg, "attn_bwd", "flash") == "flash":
+        return _sdpa_chunked_flash(cfg, q, k, v, positions[0], positions[0],
+                                   window, scale=scale)
+    return _sdpa_chunked(cfg, q, k, v, positions[0], positions[0], window,
+                         scale=scale)
 
 
 class KVCache(NamedTuple):

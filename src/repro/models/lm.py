@@ -19,9 +19,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.core.interface import RunStats
 from .common import embed_init, rmsnorm, rmsnorm_init, softcap
-from .transformer import blocks_serve, blocks_train, init_blocks, init_cache
+from .transformer import (TrainStats, blocks_serve, blocks_train,
+                          init_blocks, init_cache)
 
 Pytree = Any
 
@@ -56,9 +56,10 @@ def _embed(params: Pytree, cfg: ModelConfig, batch: Pytree) -> jax.Array:
 
 
 def backbone_train(params: Pytree, cfg: ModelConfig, batch: Pytree
-                   ) -> Tuple[jax.Array, RunStats]:
-    """Returns (final hidden states, summed ODE RunStats — detached int32
-    counters from every residual-branch solve; zeros with ode.mode='off')."""
+                   ) -> Tuple[jax.Array, TrainStats]:
+    """Returns (final hidden states, summed TrainStats — detached int32
+    counters from every residual branch; the ODE ones zero with
+    ode.mode='off')."""
     x = _embed(params, cfg, batch)
     x, stats = blocks_train(params["blocks"], cfg, x, None)
     return rmsnorm(params["final_norm"], x), stats
@@ -93,8 +94,8 @@ def chunked_ce_loss(h: jax.Array, head: jax.Array, labels: jax.Array,
 
 
 def lm_loss_and_stats(params: Pytree, cfg: ModelConfig, batch: Pytree
-                      ) -> Tuple[jax.Array, RunStats]:
-    """Like :func:`lm_loss` but also returns the integration accounting.
+                      ) -> Tuple[jax.Array, TrainStats]:
+    """Like :func:`lm_loss` but also returns the step's counters.
 
     The stats are the ``has_aux`` side of the train step's value_and_grad:
     already stop_gradient-detached inside the backbone, so they thread out

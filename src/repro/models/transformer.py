@@ -13,7 +13,7 @@ index is a cache "virtual layer" slot (DESIGN.md §3).
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +25,9 @@ from repro.core.interface import RunStats
 from .attention import (KVCache, attention_decode, attention_prefill,
                         attention_train, init_attention)
 from .common import rmsnorm, rmsnorm_init
+from .mla import init_mla, mla_train
 from .mlp import apply_mlp, init_mlp
-from .moe import apply_moe, init_moe
+from .moe import apply_moe, init_moe, route_counts
 from .ssm import MambaCache, apply_mamba_decode, apply_mamba_train, init_mamba
 from .xlstm import (LstmCache, apply_mlstm_decode, apply_mlstm_train,
                     apply_slstm_decode, apply_slstm_train, init_mlstm,
@@ -48,6 +49,7 @@ def n_cache_slots(cfg: ModelConfig) -> int:
 
 _MIXER_INIT = {
     "attn": init_attention,
+    "mla": init_mla,
     "mamba": init_mamba,
     "mlstm": init_mlstm,
     "slstm": init_slstm,
@@ -80,6 +82,8 @@ def _mixer_train_fn(cfg: ModelConfig, spec: LayerSpec, positions=None):
     # break custom_vjp's static f); attention computes its own arange.
     if spec.mixer == "attn":
         return lambda p, z: attention_train(p, cfg, spec, z, positions)
+    if spec.mixer == "mla":
+        return lambda p, z: mla_train(p, cfg, spec, z, positions)
     if spec.mixer == "mamba":
         return lambda p, z: apply_mamba_train(p, cfg, z)
     if spec.mixer == "mlstm":
@@ -107,10 +111,35 @@ def _detach_counter(c: jax.Array) -> jax.Array:
     return lax.stop_gradient(c.astype(jnp.float32)).astype(jnp.int32)
 
 
-def add_run_stats(a: RunStats, b: RunStats) -> RunStats:
-    return RunStats(a.n_accepted + b.n_accepted,
-                    a.n_rejected + b.n_rejected,
-                    a.n_fevals + b.n_fevals)
+class TrainStats(NamedTuple):
+    """A train step's counters, summed over its residual branches: the ODE
+    solves' :class:`RunStats` fields, and the MoE branches' routing of
+    their entry state (``moe.route_counts``): assignments to held experts
+    and those of them over the device budget. Detached int32 scalars."""
+    n_accepted: jax.Array
+    n_rejected: jax.Array
+    n_fevals: jax.Array
+    moe_routed: jax.Array
+    moe_dropped: jax.Array
+
+
+def zero_train_stats() -> TrainStats:
+    z = jnp.zeros((), jnp.int32)
+    return TrainStats(z, z, z, z, z)
+
+
+def add_train_stats(a: TrainStats, b: TrainStats) -> TrainStats:
+    return TrainStats(*(x + y for x, y in zip(a, b)))
+
+
+def _moe_entry_counts(params: Pytree, cfg: ModelConfig,
+                      x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Routing counts of a MoE branch at its entry state z0 (what its first
+    f-eval sees), outside the gradient."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    p, z = lax.stop_gradient((params, x))
+    counts = route_counts(p["mlp"], cfg, rmsnorm(p["mlp_norm"], z.astype(cdt)))
+    return tuple(_detach_counter(c) for c in counts)
 
 
 def _residual_branch(cfg: ModelConfig, branch_params: Pytree, x: jax.Array,
@@ -153,16 +182,19 @@ def _residual_branch(cfg: ModelConfig, branch_params: Pytree, x: jax.Array,
 
 def layer_train(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
                 x: jax.Array, positions: jax.Array = None
-                ) -> Tuple[jax.Array, RunStats]:
+                ) -> Tuple[jax.Array, TrainStats]:
     mixer = _mixer_train_fn(cfg, spec, None)
-    x, stats = _residual_branch(
+    x, ode = _residual_branch(
         cfg, {"norm": params["mixer_norm"], "inner": params["mixer"]}, x,
         mixer)
+    stats = TrainStats(*ode, *zero_train_stats()[3:])
     if spec.mlp != "none":
+        moe = (_moe_entry_counts(params, cfg, x) if spec.mlp == "moe"
+               else stats[3:])
         mlp = _mlp_train_fn(cfg, spec)
         x, s2 = _residual_branch(
             cfg, {"norm": params["mlp_norm"], "inner": params["mlp"]}, x, mlp)
-        stats = add_run_stats(stats, s2)
+        stats = add_train_stats(stats, TrainStats(*s2, *moe))
     return x, stats
 
 
@@ -187,23 +219,24 @@ def init_blocks(key: jax.Array, cfg: ModelConfig) -> Pytree:
 
 
 def blocks_train(params: Pytree, cfg: ModelConfig, x: jax.Array,
-                 positions: jax.Array) -> Tuple[jax.Array, RunStats]:
-    """Returns (activations, summed ODE RunStats over every residual branch).
+                 positions: jax.Array) -> Tuple[jax.Array, TrainStats]:
+    """Returns (activations, :class:`TrainStats` summed over every residual
+    branch).
 
     Stats counters are detached int32 scalars (see ``_residual_branch``), so
     carrying their sum through the period scan is float0-safe.
     """
-    stats = zero_run_stats()
+    stats = zero_train_stats()
     for i, spec in enumerate(cfg.prelude):
         x, s = layer_train(params["prelude"][i], cfg, spec, x, positions)
-        stats = add_run_stats(stats, s)
+        stats = add_train_stats(stats, s)
 
     if cfg.period:
         def period_fn(carry, pp):
             xc, sc = carry
             for j, spec in enumerate(cfg.period):
                 xc, s = layer_train(pp[f"sub{j}"], cfg, spec, xc, positions)
-                sc = add_run_stats(sc, s)
+                sc = add_train_stats(sc, s)
             return (xc, sc), None
 
         (x, stats), _ = lax.scan(period_fn, (x, stats), params["period"])
@@ -214,8 +247,17 @@ def blocks_train(params: Pytree, cfg: ModelConfig, x: jax.Array,
 # Serve path (prefill / decode) — explicit ALF unroll with cache threading
 # ---------------------------------------------------------------------------
 
+def _refuse_mla(spec: LayerSpec) -> None:
+    if spec.mixer == "mla":
+        raise NotImplementedError(
+            "serving an 'mla' layer needs a latent KV cache (c_kv, k_pe per "
+            "slot), which the serve path does not have; MLA runs on the "
+            "train path only")
+
+
 def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
                      s_max: int) -> Pytree:
+    _refuse_mla(spec)
     slots = n_cache_slots(cfg)
     if spec.mixer == "attn":
         return KVCache.init(cfg, slots, batch, s_max)
@@ -289,6 +331,7 @@ def layer_serve(params: Pytree, cfg: ModelConfig, spec: LayerSpec,
                 ) -> Tuple[jax.Array, Pytree]:
     """One layer, serve mode. pos_info: positions [B,S] (prefill) or scalar
     pos (decode)."""
+    _refuse_mla(spec)
     ode = cfg.ode
     cdt = jnp.dtype(cfg.compute_dtype)
 

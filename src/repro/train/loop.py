@@ -8,10 +8,11 @@ the retrace contract ``analysis.trace_audit.run_train_audit`` checks.
 Gradients come from ``jax.value_and_grad(lm_loss_and_stats, has_aux=True)``:
 the continuous-depth model's residual branches are native
 ``solve(..., gradient=MALI(...))`` calls, and the aux
-:class:`~repro.core.interface.RunStats` threads the per-step integration
-accounting (f-evals, accepted/rejected trials) out of the jitted step —
-the counters are laundered inside the model (R002c), so summing them over
-a microbatch scan here is float0-safe.
+:class:`~repro.models.transformer.TrainStats` threads the per-step
+integration accounting (f-evals, accepted/rejected trials) and the MoE
+routing counts out of the jitted step — the counters are laundered inside
+the model (R002c), so summing them over a microbatch scan here is
+float0-safe.
 
 :class:`TrainLoop` is the registered driver axis (R004 lint: every
 registered loop overrides every abstract member and appears in tests):
@@ -28,10 +29,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.core.interface import RunStats
 from repro.distributed.sharding import ambient_mesh, param_shardings
 from repro.models.lm import lm_loss_and_stats
-from repro.models.transformer import add_run_stats, zero_run_stats
+from repro.models.transformer import (TrainStats, add_train_stats,
+                                      zero_train_stats)
 from repro.optim.compression import EFState, compress_grads, init_ef_state
 from repro.optim.optimizer import OptimizerConfig, OptState, apply_updates
 
@@ -45,8 +46,8 @@ def _split_microbatches(batch: Pytree, n: int) -> Pytree:
 
 def loss_and_grads(params: Pytree, batch: Pytree, *, cfg: ModelConfig,
                    microbatches: int = 1
-                   ) -> Tuple[jax.Array, RunStats, Pytree]:
-    """(mean loss, summed RunStats, mean grads) for one global batch.
+                   ) -> Tuple[jax.Array, TrainStats, Pytree]:
+    """(mean loss, summed TrainStats, mean grads) for one global batch.
 
     With ``microbatches > 1`` the global batch is split on its leading axis
     and accumulated through a ``lax.scan`` (sequential — peak memory is one
@@ -67,12 +68,12 @@ def loss_and_grads(params: Pytree, batch: Pytree, *, cfg: ModelConfig,
     def acc(carry, mb):
         loss_acc, stats_acc, g_acc = carry
         loss, stats, g = one(params, mb)
-        return (loss_acc + loss, add_run_stats(stats_acc, stats),
+        return (loss_acc + loss, add_train_stats(stats_acc, stats),
                 _tm(jnp.add, g_acc, g)), None
 
     zeros = _tm(lambda p: jnp.zeros(p.shape, jnp.float32), params)
     (loss, stats, grads), _ = lax.scan(
-        acc, (jnp.float32(0.0), zero_run_stats(), zeros), mbs)
+        acc, (jnp.float32(0.0), zero_train_stats(), zeros), mbs)
     inv = 1.0 / microbatches
     return loss * inv, stats, _tm(lambda g: g * inv, grads)
 
@@ -105,6 +106,8 @@ def train_step(params: Pytree, opt_state: OptState, ef: Optional[EFState],
     metrics["ode_accepted"] = stats.n_accepted
     metrics["ode_rejected"] = stats.n_rejected
     metrics["ode_fevals"] = stats.n_fevals
+    metrics["moe_routed"] = stats.moe_routed
+    metrics["moe_dropped"] = stats.moe_dropped
     return params, opt_state, ef, metrics
 
 

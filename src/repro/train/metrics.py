@@ -3,9 +3,10 @@
 A :class:`StepRecord` is one row of the run's metrics table: optimizer
 scalars (loss, lr, grad-norm), the step's host time and the seconds of its
 host phases (the ``train.*`` spans of :mod:`repro.train.spans`), the
-backend compiles it triggered, and the continuous-depth accounting —
+backend compiles it triggered, the continuous-depth accounting —
 dynamics evaluations and accepted/rejected trials from the step's
-``solve()`` calls (threaded out of the jitted step as RunStats aux).
+``solve()`` calls — and the MoE layers' routing counts (threaded out of
+the jitted step as ``TrainStats`` aux).
 
 :class:`MetricsEmitter` is the registered sink axis (R004): stdout JSON
 lines, a JSONL file, or an in-memory list for tests.
@@ -33,6 +34,9 @@ class StepRecord:
     fevals: int             # dynamics evaluations across the step's solves
     accepted: int           # accepted solver trials
     rejected: int           # rejected solver trials
+    moe_routed: int         # token assignments to held experts, each MoE
+                            # branch routing its entry state once
+    moe_dropped: int        # of those, over the device budget
 
     def as_row(self) -> Dict:
         return dataclasses.asdict(self)

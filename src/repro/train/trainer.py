@@ -198,8 +198,10 @@ class Trainer:
                         raise RuntimeError(f"non-finite loss at step {step}")
                     lr = float(metrics["lr"])
                     grad_norm = float(metrics["grad_norm"])
-                    fevals, accepted, rejected = (int(metrics[k]) for k in (
-                        "ode_fevals", "ode_accepted", "ode_rejected"))
+                    fevals, accepted, rejected, routed, dropped = (
+                        int(metrics[k]) for k in (
+                            "ode_fevals", "ode_accepted", "ode_rejected",
+                            "moe_routed", "moe_dropped"))
                 wall_s = spans.wall_s()
                 with spans.phase("record"):
                     state = TrainState(p, o, carry,
@@ -211,7 +213,8 @@ class Trainer:
                         dispatch_s=sec["dispatch"], wait_s=sec["wait"],
                         readback_s=sec["readback"],
                         compiles=spans.compiles, fevals=fevals,
-                        accepted=accepted, rejected=rejected)
+                        accepted=accepted, rejected=rejected,
+                        moe_routed=routed, moe_dropped=dropped)
                     self.records[step] = rec
                     self.emitter.emit(rec)
                     if step % tc.log_every == 0 or step == tc.steps - 1:

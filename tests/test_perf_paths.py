@@ -133,23 +133,44 @@ def test_flash_vs_direct_small():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_moe_sort_dispatch_matches_cumsum():
-    """Rank-within-expert positions: sort-based == one-hot-cumsum oracle."""
-    n, k, e = 128, 3, 16
+@pytest.mark.parametrize("rows,held,n_held_ids", [
+    (384, 16, 16),    # every expert held, the budget not binding
+    (40, 16, 6),      # six experts held of sixteen, the budget binding
+    (200, 8, 3),      # three held of eight: most groups empty
+], ids=["dropless", "budget", "empty-groups"])
+def test_moe_sort_dispatch_matches_cumsum(rows, held, n_held_ids):
+    """Rows grouped by expert: the sort-based dispatch keeps the budget's
+    highest-affinity held assignments and puts each at its group's start
+    plus its rank among that expert's kept assignments in (token, choice)
+    order (the one-hot-cumsum oracle); the group sizes count them; empty
+    rows sit in the last group."""
+    from repro.models.moe import dispatch
+    n, k = 128, 3
     rng = np.random.default_rng(7)
-    gate_idx = jnp.asarray(rng.integers(0, e, (n, k)), jnp.int32)
-    onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32).reshape(n * k, e)
-    pos_old = (((jnp.cumsum(onehot, 0) - onehot) * onehot).sum(-1)
-               ).astype(jnp.int32)
-    eidx = gate_idx.reshape(-1)
-    order = jnp.argsort(eidx, stable=True)
-    sorted_e = eidx[order]
-    gs = jnp.searchsorted(sorted_e, jnp.arange(e, dtype=eidx.dtype),
-                          side="left")
-    pos_sorted = jnp.arange(n * k, dtype=jnp.int32) \
-        - gs[sorted_e].astype(jnp.int32)
-    pos_new = jnp.zeros((n * k,), jnp.int32).at[order].set(pos_sorted)
-    np.testing.assert_array_equal(np.asarray(pos_old), np.asarray(pos_new))
+    ids = rng.integers(0, held, (n, k))
+    ids[ids >= n_held_ids] += held          # the rest are not held here
+    vals = jnp.asarray(rng.random((n, k)), jnp.float32)
+    local = jnp.asarray(ids.reshape(-1), jnp.int32)
+    is_held = (local >= 0) & (local < held)
+    pick, keep, sizes = (np.asarray(a) for a in dispatch(
+        vals, local, is_held, rows, held))
+    aff = np.asarray(vals).reshape(-1)
+    held_ids = np.flatnonzero(np.asarray(is_held))
+    kept = np.sort(held_ids[np.lexsort((held_ids, -aff[held_ids]))][:rows])
+    onehot = jax.nn.one_hot(local[kept], held, dtype=jnp.float32)
+    rank = np.asarray((((jnp.cumsum(onehot, 0) - onehot) * onehot).sum(-1)
+                       ).astype(jnp.int32))
+    counts = np.bincount(np.asarray(local)[kept], minlength=held)
+    start = np.cumsum(counts) - counts
+    want = np.full(rows, -1)
+    want[start[np.asarray(local)[kept]] + rank] = kept
+    assert np.array_equal(np.where(keep, pick, -1), want)
+    group = np.repeat(np.arange(held), sizes)
+    assert np.array_equal(group[keep], np.asarray(local)[pick[keep]])
+    assert (group[~keep] == held - 1).all() and not keep[len(kept):].any()
+    assert sizes.sum() == rows
+    assert np.array_equal(sizes - np.eye(held, dtype=int)[-1]
+                          * (rows - len(kept)), counts)
 
 
 def test_hint_is_noop_without_mesh():

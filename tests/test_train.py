@@ -61,7 +61,8 @@ def test_step_records_account_for_the_odes(clean_run):
     row = recs[0].as_row()
     assert set(row) == {"step", "loss", "lr", "grad_norm", "wall_s",
                         "batch_s", "dispatch_s", "wait_s", "readback_s",
-                        "compiles", "fevals", "accepted", "rejected"}
+                        "compiles", "fevals", "accepted", "rejected",
+                        "moe_routed", "moe_dropped"}
 
 
 def test_memory_emitter_collects_every_step(clean_run):
